@@ -14,6 +14,8 @@ from __future__ import annotations
 import sys
 import traceback
 
+from repro import compile_cache
+
 from . import (
     complexity_scaling,
     engines_throughput,
@@ -72,6 +74,7 @@ def main() -> None:
     if unknown:
         print(f"unknown suites {unknown}; available: {sorted(SUITES)}")
         raise SystemExit(2)
+    compile_cache.enable()
     failures = []
     for name in wanted:
         print(f"\n=== {name} " + "=" * (70 - len(name)))

@@ -135,24 +135,28 @@ def _slo_row(name: str, slo, rate: float, extra=None) -> dict:
     return row
 
 
-def _expert_cache_slo(cfg: dict) -> dict:
+def expert_cache_track(layers: int, experts: int, steps: int, seed: int = 0):
+    """Serve ``steps`` routed-count vectors through one
+    :class:`~repro.serve.expert_cache.OGBExpertCache` decision each,
+    open-loop at ``LOAD_FACTOR`` of the capacity measured here.
+
+    Returns ``(slo, offered_rate, cache)``."""
     ec = OGBExpertCache(
         ExpertCacheConfig(
-            n_layers=cfg["layers"],
-            n_experts=cfg["experts"],
+            n_layers=layers,
+            n_experts=experts,
             resident_fraction=0.25,
-            horizon_steps=cfg["serve_steps"],
+            horizon_steps=steps,
             bytes_per_expert=64 << 20,  # a 64MB expert: swap traffic in bytes
         ),
-        seed=0,
+        seed=seed,
     )
-    rng = np.random.default_rng(0)
-    shape = (cfg["layers"], cfg["experts"])
+    rng = np.random.default_rng(seed)
+    shape = (layers, experts)
     # pre-generated routed-count vectors: payload synthesis must not
     # pollute the decision latency
     payloads = [
-        rng.poisson(5.0, shape).astype(np.float32)
-        for _ in range(cfg["serve_steps"])
+        rng.poisson(5.0, shape).astype(np.float32) for _ in range(steps)
     ]
     for p in payloads[:20]:  # warmup: compile + residency steady-state
         ec.step(p)
@@ -163,27 +167,19 @@ def _expert_cache_slo(cfg: dict) -> dict:
     rate = LOAD_FACTOR / per_step
 
     loop = ContinuousServingLoop(lambda batch: ec.step(batch[0]))
-    slo = loop.run(payloads, rate)
-    swap_bytes = (ec.swapped_in + ec.swapped_out) * ec.cfg.bytes_per_expert
-    return _slo_row(
-        "expert_cache",
-        slo,
-        rate,
-        extra={
-            "mean_hit_ratio": ec.mean_hit_ratio,
-            "swapped_in": ec.swapped_in,
-            "swapped_out": ec.swapped_out,
-            "swap_gb_total": swap_bytes / 1e9,
-        },
-    )
+    return loop.run(payloads, rate), rate, ec
 
 
-def _stream_window_slo(cfg: dict, n: int, c: int) -> dict:
+def stream_window_track(n: int, c: int, window: int, steps: int,
+                        seed: int = 1):
+    """Serve ``steps`` id batches of ``window`` zipf(0.9) requests through
+    one resumable dense-OGB ``api.run(carry=...)`` window each, open-loop
+    at ``LOAD_FACTOR`` of the capacity measured here.
+
+    Returns ``(slo, offered_rate)``."""
     pd = api.policy_def("ogb")
-    window = cfg["window"]
-    steps = cfg["serve_steps"]
     horizon = steps * window
-    rng = np.random.default_rng(1)
+    rng = np.random.default_rng(seed)
     zipf_p = 1.0 / np.arange(1, n + 1) ** 0.9
     zipf_p /= zipf_p.sum()
     payloads = [
@@ -216,10 +212,34 @@ def _stream_window_slo(cfg: dict, n: int, c: int) -> dict:
     rate = LOAD_FACTOR / per_step
 
     state["carry"] = None  # fresh policy for the measured run
-    slo = ContinuousServingLoop(decide).run(payloads, rate)
+    return ContinuousServingLoop(decide).run(payloads, rate), rate
+
+
+def _expert_cache_slo(cfg: dict) -> dict:
+    slo, rate, ec = expert_cache_track(
+        cfg["layers"], cfg["experts"], cfg["serve_steps"]
+    )
+    swap_bytes = (ec.swapped_in + ec.swapped_out) * ec.cfg.bytes_per_expert
+    return _slo_row(
+        "expert_cache",
+        slo,
+        rate,
+        extra={
+            "mean_hit_ratio": ec.mean_hit_ratio,
+            "swapped_in": ec.swapped_in,
+            "swapped_out": ec.swapped_out,
+            "swap_gb_total": swap_bytes / 1e9,
+        },
+    )
+
+
+def _stream_window_slo(cfg: dict, n: int, c: int) -> dict:
+    slo, rate = stream_window_track(
+        n, c, cfg["window"], cfg["serve_steps"]
+    )
     return _slo_row(
         "stream_window", slo, rate,
-        extra={"requests_per_decision": window},
+        extra={"requests_per_decision": cfg["window"]},
     )
 
 

@@ -9,7 +9,7 @@ into a recompile and the perf claims into fiction.
 :func:`track_compiles` watches two signals at once:
 
 * **trace compiles** — jax's own ``log_compiles`` stream ("Compiling
-  <name> with global shapes...", emitted once per new (function, avals)
+  jit(<name>) with global shapes...", emitted once per new (function, avals)
   trace, AOT or not), captured with a logging handler;
 * **executable compiles** — misses of ``api._EXEC_CACHE``, reported by
   the hook :func:`repro.cachesim.api.add_compile_listener`; this is the
@@ -38,14 +38,11 @@ import jax
 
 __all__ = ["CompileEvent", "CompileLog", "track_compiles"]
 
-_COMPILING_RE = re.compile(r"^Compiling ([^\s]+) ")
+_COMPILING_RE = re.compile(r"^Compiling jit\(([^\s()]+)\) ")
 
-#: loggers that carry the log_compiles "Compiling <name> ..." records
-#: (pxla on current jax; dispatch kept as a fallback for older layouts)
-_JAX_LOGGERS = (
-    "jax._src.interpreters.pxla",
-    "jax._src.dispatch",
-)
+#: the logger that carries the log_compiles "Compiling jit(<name>) ..."
+#: records
+_JAX_LOGGERS = ("jax._src.interpreters.pxla",)
 
 
 @dataclass(frozen=True)

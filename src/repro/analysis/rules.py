@@ -152,7 +152,7 @@ _TRACE_ENTRY_CALLS = {
     "jax.checkpoint",
     "jax.remat",
     "shard_map",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
     "pl.pallas_call",
     "pallas_call",
     "jax.eval_shape",

@@ -214,16 +214,24 @@ def clear_executable_cache() -> None:
 
 
 def _compiled(jitted, carry, chunks):
-    """AOT-compiled executable, memoized on (step, carry/chunk shapes).
+    """AOT-compiled executable, memoized on (step, carry/chunk shapes and
+    placements).
 
     ``jit.lower().compile()`` bypasses jit's own call cache, so without this
     every :func:`run` would recompile; with it, repeated runs of the same
-    shapes (goldens, parity tests, benchmark repeats) compile once."""
+    shapes (goldens, parity tests, benchmark repeats) compile once.  An
+    executable is bound to the devices it was compiled for, so the
+    shardings are part of the key: the same shapes on another device, or
+    sharded over a mesh, get their own executable."""
     key = (
         id(jitted),  # _scan_jit/_sweep_jit are memoized, so ids are stable
         chunks.shape,
+        chunks.sharding,
         jax.tree.structure(carry),
-        tuple((x.shape, str(x.dtype)) for x in jax.tree.leaves(carry)),
+        tuple(
+            (x.shape, str(x.dtype), getattr(x, "sharding", None))
+            for x in jax.tree.leaves(carry)
+        ),
     )
     if key not in _EXEC_CACHE:
         _EXEC_CACHE[key] = jitted.lower(carry, chunks).compile()

@@ -36,6 +36,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.core.regret import best_static_hits
 from repro.dist import sharding as _sharding
@@ -122,6 +123,7 @@ def _build_fleet_carries(
     sizes,
     costs,
     init_kw: dict,
+    sharding=None,
 ):
     """Stacked tenant carries + the per-tenant resolved etas.
 
@@ -129,9 +131,9 @@ def _build_fleet_carries(
     horizon — a tenant replaying a T/E slice of a fleet workload needs the
     Theorem-3.1 rate at T/E, not at the fleet-aggregate T (which is what a
     naive ``sweep()``-style resolution at the full trace horizon would
-    give it)."""
+    give it).  With a tenant ``sharding`` the stack is built block by
+    block on the devices that hold it (:func:`_stack_on_devices`)."""
     resolved = []
-    carries = []
     for t in range(len(caps)):
         e = eta_list[t]
         if e is None and pd.default_eta is not None:
@@ -139,20 +141,35 @@ def _build_fleet_carries(
                 int(catalog_size), int(caps[t]), int(horizons[t]), window
             )
         resolved.append(e)
-        carries.append(
-            pd.init(
-                int(catalog_size),
-                int(caps[t]),
-                seed=int(seeds[t]),
-                eta=e,
-                horizon=int(horizons[t]),
-                n_slots=n_slots,
-                sizes=sizes,
-                costs=costs,
-                **init_kw,
-            )
+
+    def init(t):
+        return pd.init(
+            int(catalog_size),
+            int(caps[t]),
+            seed=int(seeds[t]),
+            eta=resolved[t],
+            horizon=int(horizons[t]),
+            n_slots=n_slots,
+            sizes=sizes,
+            costs=costs,
+            **init_kw,
         )
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *carries)
+
+    def stack(tenants):
+        # rows are written in place into one preallocated stack, so the
+        # build never holds a second copy of the fleet's carries
+        first = init(tenants[0])
+        out = jax.tree.map(
+            lambda x: jnp.zeros((len(tenants),) + x.shape, x.dtype), first
+        )
+        for i, t in enumerate(tenants):
+            out = _set_row(out, first if i == 0 else init(t), i)
+        return out
+
+    if sharding is None:
+        stacked = stack(range(len(caps)))
+    else:
+        stacked = _stack_on_devices(stack, len(caps), sharding)
     if any(r is not None for r in resolved):
         etas_out = np.array(
             [np.nan if r is None else float(r) for r in resolved]
@@ -160,6 +177,37 @@ def _build_fleet_carries(
     else:
         etas_out = None
     return stacked, etas_out
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _set_row(stacked, row, i):
+    """``stacked[i] = row`` for every leaf, in place (the stack is donated)."""
+    return jax.tree.map(lambda s, r: s.at[i].set(r), stacked, row)
+
+
+def _stack_on_devices(stack, n_tenants: int, sharding):
+    """Tenant-sharded stacked carries, each block built on its own device.
+
+    ``stack(tenants)`` stacks the carries of a range of tenants on the
+    default device.  Building every block under ``jax.default_device`` of
+    the device that holds it means a fleet whose carries exceed one
+    device's memory never passes through a single device."""
+    blocks: dict = {}
+    shards = []
+    for dev, idx in sharding.devices_indices_map((n_tenants,)).items():
+        lo, hi, _ = idx[0].indices(n_tenants)
+        if (lo, hi) not in blocks:
+            with jax.default_device(dev):
+                blocks[(lo, hi)] = stack(range(lo, hi))
+        shards.append(jax.device_put(blocks[(lo, hi)], dev))
+    treedef = jax.tree.structure(shards[0])
+    leaves = [
+        jax.make_array_from_single_device_arrays(
+            (n_tenants,) + col[0].shape[1:], sharding, list(col)
+        )
+        for col in zip(*(jax.tree.leaves(sh) for sh in shards))
+    ]
+    return jax.tree.unflatten(treedef, leaves)
 
 
 def _reject_resume_kwargs(seeds, etas, horizons, n_slots, costs, init_kw):
@@ -178,28 +226,28 @@ def _reject_resume_kwargs(seeds, etas, horizons, n_slots, costs, init_kw):
         )
 
 
-def _place_fleet(stacked, chunks, mesh, rules):
-    """Shard the tenant axis over the mesh's data axis (if a mesh is live).
+def _tenant_sharding(n_tenants: int, mesh, rules):
+    """The tenant axis over the mesh's data axis, or None with no live mesh.
 
-    Every carry leaf and the (E, M, W) chunk block get their leading axis
-    mapped through the ``"tenants"`` logical axis of
-    :func:`repro.dist.sharding.default_rules`; non-divisible tenant counts
-    fall back to replication leaf-by-leaf (``logical_to_spec`` drops the
-    axis), so oddball fleets still run."""
+    Every fleet array (carry leaves and the (E, M, W) chunk block) leads
+    with the tenant axis, mapped through the ``"tenants"`` logical axis of
+    :func:`repro.dist.sharding.default_rules`, so one sharding places them
+    all; a tenant count the data axis does not divide is replicated
+    (``logical_to_spec`` drops the axis), so oddball fleets still run.
+    The mesh's axes are taken as Auto: the vmapped scans leave the
+    partitioning of their intermediates to the compiler, which Explicit
+    axes (the ``jax.make_mesh`` default) do not allow."""
     if mesh is None:
         mesh = _sharding.current_mesh()
         rules = rules if rules is not None else _sharding.current_rules()
     if mesh is None:
-        return stacked, chunks, False
+        return None
     if rules is None:
         rules = _sharding.default_rules()
-
-    def put(x):
-        axes = ("tenants",) + (None,) * (x.ndim - 1)
-        sh = _sharding.named_sharding(mesh, axes, rules=rules, shape=x.shape)
-        return jax.device_put(x, sh)
-
-    return jax.tree.map(put, stacked), put(chunks), True
+    mesh = mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+    return _sharding.named_sharding(
+        mesh, ("tenants",), rules=rules, shape=(n_tenants,)
+    )
 
 
 def _opt_from_counts(counts: np.ndarray, capacity: int) -> float:
@@ -257,6 +305,7 @@ def run_fleet(
         )
     chunks, used, t_used = _tenant_chunks(traces, window)
     n_tenants = chunks.shape[0]
+    sharding = _tenant_sharding(n_tenants, mesh, rules)
 
     if carry is None:
         if catalog_size is None or capacities is None:
@@ -276,7 +325,7 @@ def run_fleet(
         slots = int(n_slots) if n_slots is not None else int(caps.max())
         stacked, etas_out = _build_fleet_carries(
             pd, catalog_size, caps, seed_arr, eta_list, hor, window, slots,
-            sizes, costs, init_kw,
+            sizes, costs, init_kw, sharding,
         )
     else:
         _reject_resume_kwargs(seeds, etas, horizons, n_slots, costs, init_kw)
@@ -296,15 +345,10 @@ def run_fleet(
         etas_out = None
 
     jitted = api._fleet_jit(pd.step)
-    stacked, chunks, sharded = _place_fleet(stacked, chunks, mesh, rules)
+    if sharding is not None:
+        stacked, chunks = jax.device_put((stacked, chunks), sharding)
     t0 = time.perf_counter()
-    if sharded:
-        # jit's own call cache is sharding-aware; the AOT executable cache
-        # keys only on shapes, so mixing placements must bypass it
-        final, out = jitted(stacked, chunks)
-    else:
-        compiled = api._compiled(jitted, stacked, chunks)
-        final, out = compiled(stacked, chunks)
+    final, out = api._compiled(jitted, stacked, chunks)(stacked, chunks)
     jax.block_until_ready(out)
     wall = time.perf_counter() - t0
 

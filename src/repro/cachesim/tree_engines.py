@@ -741,10 +741,12 @@ def make_ogb_tree_chunk(catalog_size: int, v: int, radix: int, sample: str,
     * the threshold solve uses the bucket mean-clip mass — exact except for
       the <= 2 buckets straddling ``rho`` and ``rho + 1``, so rho carries
       an O(bucket width) quantization;
-    * the upper clip ``y <- min(y, 1 + rho)`` is applied to an item only
-      when it is touched, so an item far above the cap decays a little
-      later than in the dense replay (bounded by its last chunk's eta
-      mass).  The differential test bounds the combined drift.
+    * a touched item is first re-anchored to the clipped f of the dense
+      step (``y <- clip(y, rho, 1 + rho)``): one below ``rho`` holds f = 0,
+      not a debt.  The upper clip is applied to an item only when it is
+      touched, so an item far above the cap decays a little later than in
+      the dense replay (bounded by its last chunk's eta mass).  The
+      differential test bounds the combined drift.
     """
     poisson = sample == "poisson"
 
@@ -794,9 +796,9 @@ def make_ogb_tree_chunk(catalog_size: int, v: int, radix: int, sample: str,
         first = a[ids] == lanes
         scratch = a.at[ids].set(_I32_MAX)  # restore
 
-        # --- gradient step: upper-clip touched items, add eta per request ---
+        # --- gradient step: clip touched items, add eta per request ---
         yold = y[ids]
-        y = y.at[ids].min(1.0 + rho)
+        y = y.at[ids].min(1.0 + rho).at[ids].max(rho)
         y = y.at[ids].add(eta)
         ynew = y[ids]
 
@@ -1099,9 +1101,9 @@ def make_sized_ogb_tree_chunk(catalog_size: int, kk: int, v: int, radix: int,
         first = a[ids] == lanes
         scratch = a.at[ids].set(_I32_MAX)
 
-        # --- gradient step: upper-clip touched, add eta * w_j per request ---
+        # --- gradient step: clip touched, add eta * w_j per request ---
         yold = y[ids]
-        y = y.at[ids].min(1.0 + sj * rho)
+        y = y.at[ids].min(1.0 + sj * rho).at[ids].max(sj * rho)
         y = y.at[ids].add(eta * wj)
         ynew = y[ids]
 

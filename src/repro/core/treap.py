@@ -26,51 +26,7 @@ from __future__ import annotations
 import random
 from typing import Any, Iterator, Optional, Tuple
 
-try:  # pragma: no cover - import guard
-    from sortedcontainers import SortedList
-
-    _HAVE_SORTEDCONTAINERS = True
-except ImportError:  # pragma: no cover
-    _HAVE_SORTEDCONTAINERS = False
-
-    import bisect
-
-    class SortedList:  # type: ignore[no-redef]
-        """Minimal bisect-backed fallback with SortedList's used surface.
-
-        O(n) insertion/removal (list shifting) — correct but slow; production
-        runs should prefer the treap engine (``make_store`` already falls back
-        to it) or install sortedcontainers.
-        """
-
-        __slots__ = ("_l",)
-
-        def __init__(self):
-            self._l = []
-
-        def __len__(self):
-            return len(self._l)
-
-        def __getitem__(self, i):
-            return self._l[i]
-
-        def __iter__(self):
-            return iter(self._l)
-
-        def add(self, v):
-            bisect.insort(self._l, v)
-
-        def remove(self, v):
-            i = bisect.bisect_left(self._l, v)
-            if i == len(self._l) or self._l[i] != v:
-                raise ValueError(f"{v!r} not in list")
-            del self._l[i]
-
-        def pop(self, i=-1):
-            return self._l.pop(i)
-
-        def bisect_left(self, v):
-            return bisect.bisect_left(self._l, v)
+from sortedcontainers import SortedList
 
 
 class _Node:
@@ -266,7 +222,5 @@ def make_store(kind: str = "sorted", seed: int = 0):
     if kind == "treap":
         return Treap(seed=seed)
     if kind == "sorted":
-        if _HAVE_SORTEDCONTAINERS:
-            return SortedKeyStore(seed=seed)
-        return Treap(seed=seed)
+        return SortedKeyStore(seed=seed)
     raise ValueError(f"unknown ordered-store kind: {kind!r}")

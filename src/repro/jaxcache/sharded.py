@@ -23,29 +23,6 @@ from jax.sharding import PartitionSpec as P
 
 from .fractional import DEFAULT_BISECT_ITERS, DEFAULT_WARM_SWEEPS, warm_bracket_hi
 
-try:  # jax >= 0.5 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version compat
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# pvary only exists on newer jax; older versions need check_rep=False instead
-_pvary = getattr(jax.lax, "pvary", None)
-_HAVE_PVARY = _pvary is not None
-
-
-def _mark_varying(x, axes):
-    return _pvary(x, axes) if _HAVE_PVARY else x
-
-
-def _shard_map_relaxed(fn, *, mesh, in_specs, out_specs):
-    """shard_map without replication checking on jax versions lacking pvary."""
-    if _HAVE_PVARY:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
 def _local_histogram(
     ids: jax.Array, shard_size: int, offset: jax.Array
 ) -> jax.Array:
@@ -168,14 +145,14 @@ def make_sharded_step(
         return jnp.clip(y - tau, 0.0, 1.0), reward, tau
 
     if warm_start:
-        shard_fn = _shard_map_relaxed(
+        shard_fn = jax.shard_map(
             local_step_warm,
             mesh=mesh,
             in_specs=(f_spec, ids_spec, P()),
             out_specs=(f_spec, P(), P()),
         )
     else:
-        shard_fn = _shard_map_relaxed(
+        shard_fn = jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(f_spec, ids_spec),
@@ -269,10 +246,6 @@ def make_fleet_step(
         y, reward = _prologue(f_local, ids_local, eta_c)
         lo = jnp.zeros_like(eta_c)
         hi = 1.0 + eta_c * jnp.float32(ids_local.shape[1])
-        # mark the carries as varying over the cache axis (their updates
-        # depend on f, which is sharded over it)
-        lo = _mark_varying(lo, (cache_axis,))
-        hi = _mark_varying(hi, (cache_axis,))
 
         def body(_, carry):
             lo, hi = carry
@@ -297,7 +270,7 @@ def make_fleet_step(
     ):
         y, reward = _prologue(f_local, ids_local, eta_c)
         # provable per-cache bracket for a feasible f: tau_e in [0, eta_e*B]
-        lo = _mark_varying(jnp.zeros_like(eta_c), (cache_axis,))
+        lo = jnp.zeros_like(eta_c)
         hi = warm_bracket_hi(eta_c * jnp.float32(ids_local.shape[1]))
         t = jnp.clip(tau_prev, lo, hi)
 
@@ -332,7 +305,7 @@ def make_fleet_step(
     par_spec = P(cache_axis)  # per-cache params slice with their cache
 
     if warm_start:
-        shard_fn = _shard_map_relaxed(
+        shard_fn = jax.shard_map(
             local_step_warm,
             mesh=mesh,
             in_specs=(f_spec, ids_spec, par_spec, par_spec, par_spec),
@@ -349,7 +322,7 @@ def make_fleet_step(
             NamedSharding(mesh, par_spec),
         )
 
-    shard_fn = _shard_map_relaxed(
+    shard_fn = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(f_spec, ids_spec, par_spec, par_spec),

@@ -14,8 +14,8 @@ with 2-3 sweeps.
 
 Kernels:
   * ``mass_kernel``  — per-block partial masses + interior counts for K taus,
-    accumulated across the grid into a single (K,) output block (TPU
-    revisiting-output pattern).
+    accumulated across the grid into one (K, 128) lane-partial output block
+    (TPU revisiting-output pattern); the candidates are scalars in SMEM.
   * ``apply_kernel`` — elementwise f' = clip(f + eta*counts - tau, 0, 1).
 
 Blocks are (block_rows, 128) f32: 128-lane aligned for the VPU; the default
@@ -49,15 +49,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 DEFAULT_BLOCK_ROWS = 256
 DEFAULT_K = 64
-_K_CHUNK = 8
 
 
 def mass_kernel(f_ref, c_ref, taus_ref, mass_ref, cnt_ref, *, eta: float, k: int):
-    """Accumulate sum(clip(y - tau_j, 0, 1)) and interior counts over blocks."""
+    """Accumulate sum(clip(y - tau_j, 0, 1)) and interior counts over blocks.
+
+    ``taus_ref`` is the (K,) candidate vector in SMEM; ``mass_ref`` and
+    ``cnt_ref`` are (K, 128) lane-partial accumulators (row j for tau_j),
+    summed over lanes by the wrapper, so every vector stays 2-D."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -68,30 +72,13 @@ def mass_kernel(f_ref, c_ref, taus_ref, mass_ref, cnt_ref, *, eta: float, k: int
     y = f_ref[...].astype(jnp.float32) + jnp.float32(eta) * c_ref[...].astype(
         jnp.float32
     )
-    taus = taus_ref[...]  # (k,)
-
-    # chunk over candidates to bound VMEM: (chunk, rows, lanes)
-    mass_acc = jnp.zeros((k,), jnp.float32)
-    cnt_acc = jnp.zeros((k,), jnp.float32)
-    n_chunks = k // _K_CHUNK
-
-    def chunk_body(c, carry):
-        mass_acc, cnt_acc = carry
-        t = jax.lax.dynamic_slice(taus, (c * _K_CHUNK,), (_K_CHUNK,))
-        z = y[None, :, :] - t[:, None, None]  # (chunk, rows, lanes)
-        clipped = jnp.clip(z, 0.0, 1.0)
-        m = jnp.sum(clipped, axis=(1, 2))  # (chunk,)
-        interior = jnp.logical_and(z > 0.0, z < 1.0)
-        n = jnp.sum(interior.astype(jnp.float32), axis=(1, 2))
-        mass_acc = jax.lax.dynamic_update_slice(mass_acc, m, (c * _K_CHUNK,))
-        cnt_acc = jax.lax.dynamic_update_slice(cnt_acc, n, (c * _K_CHUNK,))
-        return mass_acc, cnt_acc
-
-    mass_acc, cnt_acc = jax.lax.fori_loop(
-        0, n_chunks, chunk_body, (mass_acc, cnt_acc)
-    )
-    mass_ref[...] += mass_acc
-    cnt_ref[...] += cnt_acc
+    for j in range(k):
+        z = y - taus_ref[j]
+        mass_ref[j:j + 1, :] += jnp.sum(
+            jnp.clip(z, 0.0, 1.0), axis=0, keepdims=True
+        )
+        interior = jnp.logical_and(z > 0.0, z < 1.0).astype(jnp.float32)
+        cnt_ref[j:j + 1, :] += jnp.sum(interior, axis=0, keepdims=True)
 
 
 def apply_kernel(f_ref, c_ref, tau_ref, out_ref, *, eta: float):
@@ -112,24 +99,25 @@ def _grid_masses(
     rows = f2.shape[0]
     k = taus.shape[0]
     grid = (rows // block_rows,)
-    return pl.pallas_call(
+    mass, cnt = pl.pallas_call(
         functools.partial(mass_kernel, eta=eta, k=k),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((k,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((k,), lambda i: (0,)),
-            pl.BlockSpec((k,), lambda i: (0,)),
+            pl.BlockSpec((k, LANES), lambda i: (0, 0)),
+            pl.BlockSpec((k, LANES), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((k,), jnp.float32),
-            jax.ShapeDtypeStruct((k,), jnp.float32),
+            jax.ShapeDtypeStruct((k, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((k, LANES), jnp.float32),
         ],
         interpret=interpret,
     )(f2, c2, taus)
+    return jnp.sum(mass, axis=1), jnp.sum(cnt, axis=1)
 
 
 def _grid_apply(
@@ -148,7 +136,7 @@ def _grid_apply(
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(f2.shape, f2.dtype),

@@ -10,7 +10,9 @@ Grid: (batch, kv_head, s_blocks) with the s dimension innermost (TPU executes
 the grid sequentially, so VMEM scratch carries the running max / denominator /
 accumulator across s-blocks — the standard flash pattern).  The q-group
 (H/Hkv queries sharing one kv head) rides along, giving the MXU a
-(group x D) @ (D x s_blk) matmul per block.
+(group x D) @ (D x s_blk) matmul per block.  K/V are read head-major,
+(B, Hkv, S, D), so a block's last two dims are (s_blk, D) as the TPU tiling
+requires; the per-sequence lengths are scalar-prefetched into SMEM.
 """
 
 from __future__ import annotations
@@ -27,11 +29,11 @@ NEG_INF = -1e30
 
 
 def decode_kernel(
-    q_ref,  # (1, group, D)
-    k_ref,  # (1, s_blk, 1, D)
-    v_ref,  # (1, s_blk, 1, D)
-    len_ref,  # (1, 1) int32
-    out_ref,  # (1, group, D)
+    len_ref,  # (B,) int32, SMEM
+    q_ref,  # (1, 1, group, D)
+    k_ref,  # (1, 1, s_blk, D)
+    v_ref,  # (1, 1, s_blk, D)
+    out_ref,  # (1, 1, group, D)
     m_scr,  # (group, 1) f32 running max
     l_scr,  # (group, 1) f32 running denominator
     acc_scr,  # (group, D) f32 running numerator
@@ -48,9 +50,9 @@ def decode_kernel(
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32)  # (group, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (s_blk, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)  # (s_blk, D)
-    length = len_ref[0, 0]
+    k = k_ref[0, 0].astype(jnp.float32)  # (s_blk, D)
+    v = v_ref[0, 0].astype(jnp.float32)  # (s_blk, D)
+    length = len_ref[pl.program_id(0)]
 
     scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
     scores = jax.lax.dot_general(
@@ -93,24 +95,30 @@ def _grid_decode(
     group = H // Hkv
     n_s = S // s_block
     q4 = q.reshape(B, Hkv, group, D)
-    len2 = lengths.reshape(B, 1).astype(jnp.int32)
+    kt = jnp.swapaxes(k, 1, 2)  # (B, Hkv, S, D)
+    vt = jnp.swapaxes(v, 1, 2)
 
-    out = pl.pallas_call(
-        functools.partial(decode_kernel, s_block=s_block, n_s_blocks=n_s),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, Hkv, n_s),
         in_specs=[
-            pl.BlockSpec((1, 1, group, D), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, s_block, 1, D), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, s_block, 1, D), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, s: (b, 0)),
+            pl.BlockSpec((1, 1, group, D), lambda b, h, s, lens: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, s_block, D), lambda b, h, s, lens: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, s_block, D), lambda b, h, s, lens: (b, h, s, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, D), lambda b, h, s: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
+        out_specs=pl.BlockSpec(
+            (1, 1, group, D), lambda b, h, s, lens: (b, h, 0, 0)
+        ),
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, D), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(decode_kernel, s_block=s_block, n_s_blocks=n_s),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
         interpret=interpret,
-    )(q4, k, v, len2)
+    )(lengths.astype(jnp.int32), q4, kt, vt)
     return out.reshape(B, H, D)

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -17,9 +18,15 @@ def decode_attention(
     v: jax.Array,  # (B, S, Hkv, D)
     lengths: jax.Array,  # (B,) int32
     s_block: int = DEFAULT_S_BLOCK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Single-token GQA attention over a (possibly padded) KV cache."""
+    """Single-token GQA attention over a (possibly padded) KV cache.
+
+    ``interpret=None`` selects by backend: compiled Pallas on TPU, the
+    interpreter elsewhere.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     B, H, D = q.shape
     S = k.shape[1]
     if q.shape[0] != k.shape[0]:

@@ -27,10 +27,10 @@ NEG_INF = -1e30
 
 
 def prefill_kernel(
-    q_ref,  # (1, bq, 1, D)
-    k_ref,  # (1, bk, 1, D)
-    v_ref,  # (1, bk, 1, D)
-    out_ref,  # (1, bq, 1, D)
+    q_ref,  # (1, 1, bq, D)
+    k_ref,  # (1, 1, bk, D)
+    v_ref,  # (1, 1, bk, D)
+    out_ref,  # (1, 1, bq, D)
     m_scr,  # (bq, 1)
     l_scr,  # (bq, 1)
     acc_scr,  # (bq, D)
@@ -55,9 +55,9 @@ def prefill_kernel(
 
     @pl.when(k_start <= q_last)  # skip fully-masked (future) kv blocks
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)  # (bq, D)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -87,7 +87,7 @@ def prefill_kernel(
 
     @pl.when(ki == n_k - 1)
     def _finalize():
-        out_ref[0, :, 0, :] = (
+        out_ref[0, 0] = (
             acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
         ).astype(out_ref.dtype)
 
@@ -98,24 +98,28 @@ def _grid_prefill(q, k, v, block_q, block_k, interpret):
     g = H // Hkv
     n_q = S // block_q
     n_k = S // block_k
+    # head-major (B, heads, S, D): a block's last two dims are then
+    # (block, D), as the TPU tiling requires
+    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
             prefill_kernel, block_q=block_q, block_k=block_k, n_k=n_k
         ),
         grid=(B, H, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
+            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
             # GQA: q-head h streams KV head h // g — no replication needed
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, qi, ki: (b, ki, h // g, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, qi, ki: (b, ki, h // g, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(qt, kt, vt)
+    return jnp.swapaxes(out, 1, 2)

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -17,9 +18,15 @@ def flash_prefill(
     v: jax.Array,  # (B, S, Hkv, D)
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Causal GQA attention over a full prompt (no S x S buffer)."""
+    """Causal GQA attention over a full prompt (no S x S buffer).
+
+    ``interpret=None`` selects by backend: compiled Pallas on TPU, the
+    interpreter elsewhere.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     B, S, H, D = q.shape
     if H % k.shape[2]:
         raise ValueError("H must be a multiple of Hkv")

@@ -13,8 +13,8 @@ reductions — exactly the shape Pallas is good at:
   * ``bucket_mass_kernel`` — the lazy-OGB threshold solve: for K candidate
     thresholds, ``mass(t) = sum_b cnt_b * clip(mean_b - t, 0, 1)`` over the
     (V,) bucket-count / bucket-sum arrays, accumulated across grid blocks
-    into one (K,) output (TPU revisiting-output pattern, mirroring
-    ``capped_simplex.kernel.mass_kernel``).  K-way bracketing over buckets
+    into one (K, 128) lane-partial output (TPU revisiting-output pattern,
+    mirroring ``capped_simplex.kernel.mass_kernel``).  K-way bracketing over buckets
     replaces K full-catalog sweeps of the dense projection.
 
 Blocks keep the 128-lane layout of the capped_simplex kernels; inputs are
@@ -30,10 +30,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 DEFAULT_BLOCK_ROWS = 256
-_K_CHUNK = 8
+#: rows per tree-build block: a 1-D f32 output is tiled by 1024 on TPU
+SEGSUM_BLOCK_ROWS = 1024
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
@@ -46,7 +48,7 @@ def segsum_kernel(x_ref, out_ref):
 
 
 def block_segment_sums(values: jax.Array, out_size: int, radix: int, *,
-                       block_rows: int = DEFAULT_BLOCK_ROWS,
+                       block_rows: int = SEGSUM_BLOCK_ROWS,
                        interpret: Optional[bool] = None) -> jax.Array:
     """One tree-build reduction level: (out_size,) per-group sums of a
     child level, groups of ``radix`` consecutive children."""
@@ -67,7 +69,8 @@ def block_segment_sums(values: jax.Array, out_size: int, radix: int, *,
 
 
 def bucket_mass_kernel(cnt_ref, sum_ref, taus_ref, mass_ref, *, k: int):
-    """Accumulate sum_b cnt_b * clip(mean_b - tau_j, 0, 1) over blocks."""
+    """Accumulate sum_b cnt_b * clip(mean_b - tau_j, 0, 1) over blocks into
+    (K, 128) lane partials (row j for tau_j; candidates are SMEM scalars)."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -77,19 +80,9 @@ def bucket_mass_kernel(cnt_ref, sum_ref, taus_ref, mass_ref, *, k: int):
     cnt = cnt_ref[...].astype(jnp.float32)
     tot = sum_ref[...].astype(jnp.float32)
     mean = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1.0), 0.0)
-    taus = taus_ref[...]  # (k,)
-
-    mass_acc = jnp.zeros((k,), jnp.float32)
-    n_chunks = k // _K_CHUNK
-
-    def chunk_body(c, acc):
-        t = jax.lax.dynamic_slice(taus, (c * _K_CHUNK,), (_K_CHUNK,))
-        z = jnp.clip(mean[None, :, :] - t[:, None, None], 0.0, 1.0)
-        m = jnp.sum(cnt[None, :, :] * z, axis=(1, 2))  # (chunk,)
-        return jax.lax.dynamic_update_slice(acc, m, (c * _K_CHUNK,))
-
-    mass_acc = jax.lax.fori_loop(0, n_chunks, chunk_body, mass_acc)
-    mass_ref[...] += mass_acc
+    for j in range(k):
+        z = jnp.clip(mean - taus_ref[j], 0.0, 1.0)
+        mass_ref[j:j + 1, :] += jnp.sum(cnt * z, axis=0, keepdims=True)
 
 
 def bucket_masses(cnt: jax.Array, total: jax.Array, taus: jax.Array, *,
@@ -99,24 +92,22 @@ def bucket_masses(cnt: jax.Array, total: jax.Array, taus: jax.Array, *,
     candidate thresholds over (V,) bucket count/sum arrays, one pass."""
     interpret = _auto_interpret(interpret)
     k = taus.shape[0]
-    if k % _K_CHUNK:
-        raise ValueError(f"K must be a multiple of {_K_CHUNK}, got {k}")
     v = cnt.shape[0]
     cols = block_rows * LANES
     pad = -v % cols
     c2 = jnp.pad(cnt, (0, pad)).reshape(-1, LANES).astype(jnp.float32)
     s2 = jnp.pad(total, (0, pad)).reshape(-1, LANES).astype(jnp.float32)
     rows = c2.shape[0]
-    (mass,) = pl.pallas_call(
+    mass = pl.pallas_call(
         functools.partial(bucket_mass_kernel, k=k),
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((k,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=[pl.BlockSpec((k,), lambda i: (0,))],
-        out_shape=[jax.ShapeDtypeStruct((k,), jnp.float32)],
+        out_specs=pl.BlockSpec((k, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, LANES), jnp.float32),
         interpret=interpret,
     )(c2, s2, taus.astype(jnp.float32))
-    return mass
+    return jnp.sum(mass, axis=1)

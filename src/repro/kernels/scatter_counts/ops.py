@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -19,12 +20,16 @@ def scatter_counts(
     catalog_size: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     id_chunk: int = DEFAULT_ID_CHUNK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Dense float32 histogram of ``ids`` over ``[0, catalog_size)``.
 
     Negative ids are padding and ignored (they never match a catalog slot).
+    ``interpret=None`` selects by backend: compiled Pallas on TPU, the
+    interpreter elsewhere.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b = ids.shape[0]
     pad_b = (-b) % id_chunk
     ids_p = jnp.pad(ids.astype(jnp.int32), (0, pad_b), constant_values=-1)

@@ -74,13 +74,6 @@ def parse_collective_bytes(hlo_text: str) -> Dict[str, float]:
     return out
 
 
-def _normalize_cost(cost):
-    """compiled.cost_analysis() is a dict on new jax, a per-device list on old."""
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 def build_step(arch_name: str, shape_name: str, mesh, multi_pod: bool):
     """Returns (fn, example_args (ShapeDtypeStructs), in_shardings, donate)."""
     import jax
@@ -172,7 +165,7 @@ def run_cell(
             t_compile = time.time()
 
         mem = compiled.memory_analysis()
-        cost = _normalize_cost(compiled.cost_analysis())
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
         coll = parse_collective_bytes(hlo)
         from repro.launch.hlo_analysis import analyze_collectives
@@ -256,7 +249,7 @@ def run_cache_cell(mesh_kind: str, out_dir: str = RESULTS_DIR) -> Dict[str, Any]
         ids_spec = jax.ShapeDtypeStruct((B,), jnp.int32)
         lowered = step.lower(f_spec, ids_spec)
         compiled = lowered.compile()
-        cost = _normalize_cost(compiled.cost_analysis())
+        cost = compiled.cost_analysis()
         from repro.launch.hlo_analysis import analyze_collectives
 
         coll = analyze_collectives(compiled.as_text())

@@ -12,6 +12,7 @@ import numpy as np
 
 import jax
 
+from repro import compile_cache
 from repro.configs.base import get_smoke
 from repro.core.policies import make_policy
 from repro.models.model import init_params
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--page-size", type=int, default=8)
     ap.add_argument("--hot-prompts", type=int, default=6)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_smoke(args.arch)
     params = init_params(cfg, jax.random.key(0))

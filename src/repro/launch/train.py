@@ -19,9 +19,11 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs.base import get_arch, get_smoke
 from repro.dist.fault import StragglerMonitor
 from repro.dist.sharding import default_rules, use_sharding
+from repro.launch.mesh import make_debug_mesh
 from repro.train.checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint
 from repro.train.data import DataConfig, SyntheticLM
 from repro.train.optimizer import OptimizerConfig
@@ -43,6 +45,7 @@ def main() -> None:
     ap.add_argument("--mesh-data", type=int, default=0)
     ap.add_argument("--mesh-model", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
     opt_cfg = OptimizerConfig(
@@ -50,7 +53,7 @@ def main() -> None:
     )
     mesh = None
     if args.mesh_data and args.mesh_model:
-        mesh = jax.make_mesh((args.mesh_data, args.mesh_model), ("data", "model"))
+        mesh = make_debug_mesh(args.mesh_data, args.mesh_model)
 
     state = create_train_state(cfg, opt_cfg, jax.random.key(0))
     data = SyntheticLM(

@@ -22,7 +22,7 @@ NEG_INF = -1e30
 
 # Backend switch for full-sequence causal attention: the pure-jnp chunked
 # flash (default; shardable via GSPMD) or the Pallas flash_prefill kernel
-# (TPU drop-in; validated in interpret mode on CPU). Toggle via
+# (compiled on TPU, interpreted elsewhere). Toggle via
 # set_pallas_prefill(True) — parity is tested in tests/models.
 _PALLAS_PREFILL = False
 
@@ -88,7 +88,7 @@ def flash_attention(
     if _PALLAS_PREFILL and causal and q_offset == 0 and S == T:
         from repro.kernels.flash_prefill.ops import flash_prefill
 
-        return flash_prefill(q, k, v, interpret=True)
+        return flash_prefill(q, k, v)
     g = H // Hkv
     scale = 1.0 / math.sqrt(D)
     qc = min(q_chunk, S)

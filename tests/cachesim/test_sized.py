@@ -223,6 +223,26 @@ def test_ogb_sized_tracks_float64_oracle(flavor):
     assert got == pytest.approx(want, abs=5e-3), (flavor, got, want)
 
 
+@pytest.mark.parametrize("seed,n,t,cmul", [(11, 200, 6000, 8.0),
+                                           (3, 2000, 60000, 40.0)])
+def test_ogb_sized_tree_fine_grid_matches_float64_oracle(seed, n, t, cmul):
+    """With a value grid fine enough that bucket quantization vanishes, the
+    tree flavor's lazy dynamics are the oracle's: a touched item restarts
+    from its clipped f (an item below the threshold holds f = 0, not a
+    debt).  Without that re-anchoring the gap stays at ~4e-4..2e-3."""
+    trace, sizes, n, _ = _sized_instance(seed, n=n, t=t, c=0)
+    cap_bytes = cmul * float(np.mean(sizes))
+    eta, w = 0.05, 500
+    r = api.run(
+        api.policy_def("ogb_sized", flavor="tree", buckets=1 << 20), trace,
+        n, cap_bytes, window=w, sizes=sizes, eta=eta, track_opt=False,
+    )
+    total_bytes = float(np.sum(sizes[np.asarray(trace)]))
+    got = float(np.sum(np.asarray(r.reward, np.float64))) / total_bytes
+    want = _f64_sized_oracle(trace, sizes, cap_bytes, eta, w) / total_bytes
+    assert got == pytest.approx(want, abs=1e-4), (got, want)
+
+
 @given(seed=st.integers(0, 2**31 - 1))
 @settings(max_examples=10, deadline=None)
 def test_ogb_sized_scan_oracle_sweep(seed):

@@ -1,27 +1,14 @@
-"""Test-suite bootstrap: vendor a hypothesis fallback when it isn't installed.
+"""Test-suite bootstrap: keep every test on the CPU backend.
 
-Several core test modules hard-import ``hypothesis``; without this shim the
-whole tier-1 run fails at collection on machines that don't have it.  The
-stub (:mod:`_hypothesis_stub`) draws deterministic random examples with the
-same ``given``/``settings``/``strategies`` API — the real package is used
-whenever importable.
+The tests run on the CPU on any host, including one with an accelerator:
+``JAX_PLATFORMS`` is set (unless the caller already set it) before any test
+module imports JAX, so neither this process nor the child processes some
+tests start reach for the chip.  Pallas kernels then run in interpret mode.
 """
 
 import os
-import sys
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_stub
-
-    sys.modules["hypothesis"] = _hypothesis_stub
-
-# repro.dist is a hard dependency of the model/serving/training stack and is
-# part of the library proper — no collection gating.  Genuinely optional deps
-# are handled per-module (the hypothesis shim above; pytest.importorskip at
-# the test site for anything else).
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def pytest_addoption(parser):

@@ -10,8 +10,6 @@ modes are covered, and both ordered-store engines (treap / sortedcontainers).
 
 import numpy as np
 
-# real hypothesis when installed; otherwise tests/conftest.py has registered
-# the vendored fallback (tests/_hypothesis_stub.py) under this name
 from hypothesis import given, settings
 from hypothesis import strategies as st
 

@@ -1,4 +1,4 @@
-"""Property-based capped-simplex invariants (hypothesis; stub-compatible).
+"""Property-based capped-simplex invariants (hypothesis).
 
 Complements tests/core/test_projection.py with the OGB-shaped instances the
 replay engines actually produce: y = f + eta * counts with f feasible.  On
@@ -6,10 +6,6 @@ those instances the warm-started bracketed-Newton projection (lo=0,
 hi=warm_bracket_hi, tau0 seeded from the previous step) must agree with the
 cold bisection and with the float64 oracle — the warm-vs-cold contract every
 device path (scan replay, sharded, Pallas) relies on.
-
-These run under the real hypothesis package when installed and under the
-vendored deterministic stub (tests/_hypothesis_stub.py) otherwise; the test
-bodies use only the shared given/settings/strategies surface.
 """
 
 import numpy as np

@@ -16,6 +16,7 @@ from repro.dist.sharding import (
     shard,
     use_sharding,
 )
+from repro.launch.mesh import make_debug_mesh
 
 SIZES = {"data": 2, "model": 4}
 POD_SIZES = {"pod": 2, "data": 2, "model": 4}
@@ -115,7 +116,8 @@ def test_shard_is_noop_off_context():
 
 
 def test_shard_applies_constraint_in_context():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    # shard() places with with_sharding_constraint, which takes Auto axes
+    mesh = make_debug_mesh(1, 1)
     x = jnp.ones((4, 8))
     with use_sharding(mesh, default_rules()):
         y = shard(x, "batch", "heads")

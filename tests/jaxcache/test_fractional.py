@@ -85,7 +85,8 @@ from repro.jaxcache.fractional import FractionalState, ogb_batch_update
 from repro.jaxcache.sharded import make_sharded_step
 
 N, C, B, eta = 256, 32, 64, 0.04
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(2, 4)
 step, f_shard = make_sharded_step(mesh, N, C, B, eta)
 rng = np.random.default_rng(0)
 f = jax.device_put(jnp.full((N,), C / N, jnp.float32), f_shard)
@@ -123,7 +124,8 @@ from repro.jaxcache.fractional import FractionalState, ogb_batch_update
 from repro.jaxcache.sharded import make_fleet_step
 
 E, N, C, B, eta = 4, 128, 16, 32, 0.05
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(2, 4)
 step, f_shard, ids_shard = make_fleet_step(mesh, E, N, C, B, eta)
 rng = np.random.default_rng(1)
 f = jax.device_put(jnp.full((E, N), C / N, jnp.float32), f_shard)
@@ -165,7 +167,8 @@ from repro.jaxcache.fractional import FractionalState, ogb_batch_update
 from repro.jaxcache.sharded import make_fleet_step
 
 E, N, B = 4, 128, 32
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(2, 4)
 rng = np.random.default_rng(2)
 ids_all = [jnp.asarray(rng.integers(0, N, size=(E, B)), jnp.int32)
            for _ in range(4)]

@@ -23,10 +23,11 @@ from repro.models.model import forward_train, init_params, input_specs, decode_s
 from repro.train.optimizer import OptimizerConfig
 from repro.train.train_step import create_train_state, make_train_step
 from repro.launch.hlo_analysis import analyze_collectives
+from repro.launch.mesh import make_debug_mesh
 
 cfg = get_smoke("{arch}")
 shape = ShapeConfig("t", 32, 8, "{shape}")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 specs = input_specs(cfg, shape)
 if shape.kind == "train":
     opt_cfg = OptimizerConfig(total_steps=10)
@@ -45,11 +46,7 @@ else:
     with use_sharding(mesh, default_rules()):
         compiled = jax.jit(fn, in_shardings=(p_sh, c_sh, t_sh)).lower(
             params_shape, specs["cache"], specs["tokens"]).compile()
-# per-device list on older jax (kept inline: importing repro.launch.dryrun
-# for its _normalize_cost would overwrite this process's XLA_FLAGS)
 cost = compiled.cost_analysis()
-if isinstance(cost, (list, tuple)):
-    cost = cost[0] if cost else {{}}
 coll = analyze_collectives(compiled.as_text())
 print(json.dumps({{"flops": float(cost.get("flops", 0)), "collectives": coll}}))
 """
