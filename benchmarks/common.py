@@ -10,8 +10,6 @@ from __future__ import annotations
 
 import json
 import os
-import time
-from typing import Callable
 
 import numpy as np
 
@@ -33,12 +31,6 @@ def save_json(name: str, payload) -> str:
 
 def csv_row(name: str, us_per_call: float, derived: str) -> None:
     print(f"{name},{us_per_call:.3f},{derived}")
-
-
-def timed(fn: Callable, *args, **kw):
-    t0 = time.perf_counter()
-    out = fn(*args, **kw)
-    return out, time.perf_counter() - t0
 
 
 def make_policies(N, C, T, B=1, eta=None, zeta=None, seed=0, kinds=None):

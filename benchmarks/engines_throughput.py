@@ -6,8 +6,8 @@ mirror-descent engine, the OGB scan/tree replays, and the sized engines —
 GDS on the min-pair tree and the size-aware ``ogb_sized`` tree) via the one
 unified ``api.run`` path, on whatever backend JAX picks (CPU in CI).  The acceptance
 bar is **< 15 us/request for every policy** — the bound that makes the
-paper-scale (T=2e7) comparison runs feasible.  A short host-side LRU run is
-timed for the speedup column.
+paper-scale (T=2e7) comparison runs feasible.  A short host-side LRU run
+gives the baseline of the speedup column.
 
 Writes ``benchmarks/results/engines_throughput.json`` and the tracked
 top-level ``BENCH_engines.json`` so the perf trajectory is visible PR over

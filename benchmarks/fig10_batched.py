@@ -8,13 +8,15 @@ runs on device."""
 
 from __future__ import annotations
 
+import time
+
 import numpy as np
 
 from repro.cachesim.api import policy_def, run
 from repro.cachesim.traces import bursty, zipf
 from repro.core.ogb import theoretical_eta
 
-from .common import csv_row, save_json, scale, timed
+from .common import csv_row, save_json, scale
 
 
 def run_fractional(trace: np.ndarray, N: int, C: int, B: int) -> float:
@@ -47,7 +49,9 @@ def main() -> dict:
         for B in Bs:
             if B > T // 100:
                 continue
-            (ratio), dt = timed(run_fractional, trace, N, C, B)
+            t0 = time.perf_counter()
+            ratio = run_fractional(trace, N, C, B)
+            dt = time.perf_counter() - t0
             rows[B] = ratio
             csv_row(f"fig10/{tname}/B={B}", 1e6 * dt / T, f"frac_hit={ratio:.4f}")
         out[tname] = rows

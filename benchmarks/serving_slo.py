@@ -289,14 +289,13 @@ def _async_vs_sync(
             "req_per_sec": t / best[prefetch],
             "us_per_request": 1e6 * best[prefetch] / t,
             "ingest_seconds": r.ingest_seconds,
-            "device_seconds": r.device_seconds,
             "host_seconds": r.host_seconds,
         }
         csv_row(
             f"serving/stream_prefetch={prefetch}",
             1e6 * best[prefetch] / t,
             f"T={t} {t / best[prefetch]:.0f}req/s "
-            f"ing={r.ingest_seconds:.2f}s dev={r.device_seconds:.2f}s",
+            f"ing={r.ingest_seconds:.2f}s host={r.host_seconds:.2f}s",
         )
     cores = os.cpu_count() or 1
     floor = min_speedup if cores > 1 else min(min_speedup, SINGLE_CORE_FLOOR)

@@ -40,6 +40,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.cachesim import engines as _engines
 from repro.cachesim import tree_engines as _tree_engines
@@ -213,6 +214,15 @@ def clear_executable_cache() -> None:
     _EXEC_CACHE.clear()
 
 
+def cached_executable_texts() -> list:
+    """The optimized HLO text of every memoized compiled executable.
+
+    Read-only: profiler tools map the op names of a device trace back to
+    the ``op_name`` metadata (and so to the ``jax.named_scope`` path) each
+    instruction carries here."""
+    return [c.as_text() for c in _EXEC_CACHE.values()]
+
+
 def _compiled(jitted, carry, chunks):
     """AOT-compiled executable, memoized on (step, carry/chunk shapes and
     placements).
@@ -234,7 +244,8 @@ def _compiled(jitted, carry, chunks):
         ),
     )
     if key not in _EXEC_CACHE:
-        _EXEC_CACHE[key] = jitted.lower(carry, chunks).compile()
+        with TraceAnnotation("repro.run.compile"):
+            _EXEC_CACHE[key] = jitted.lower(carry, chunks).compile()
         if _COMPILE_LISTENERS:
             info = {
                 "name": getattr(
@@ -322,81 +333,96 @@ def run(
     (:func:`repro.cachesim.tracelab.stream.run_stream`) uses this to
     overlap host ingest with device replay; the returned carry can be fed
     straight back into the next ``run`` — JAX chains the dispatches.
+
+    **Tracing:** under ``jax.profiler.trace`` the call shows as one
+    ``repro.run`` span (arguments ``windows`` and ``bytes_in``) tiled by
+    ``repro.run.upload``/``init``/``lookup`` (``compile`` nested on a
+    cache miss)/``dispatch``/``wait``/``readback``/``opt``.
     """
-    chunks, trace_used, t_used = _chunked(trace, window)
-    extras = {}
-    if carry is None:
-        if catalog_size is None or capacity is None:
-            raise ValueError("run() needs catalog_size and capacity (or carry=)")
-        if eta is None and pd.default_eta is not None:
-            eta = pd.default_eta(
-                int(catalog_size), int(capacity), t_used, window
+    trace = np.asarray(trace)
+    m = len(trace) // window
+    with TraceAnnotation("repro.run", windows=m, bytes_in=4 * m * window):
+        with TraceAnnotation("repro.run.upload"):
+            chunks, trace_used, t_used = _chunked(trace, window)
+        extras = {}
+        if carry is None:
+            if catalog_size is None or capacity is None:
+                raise ValueError(
+                    "run() needs catalog_size and capacity (or carry=)"
+                )
+            if eta is None and pd.default_eta is not None:
+                eta = pd.default_eta(
+                    int(catalog_size), int(capacity), t_used, window
+                )
+            sized_kw = {}
+            if sizes is not None:
+                sized_kw["sizes"] = np.asarray(sizes)
+            if costs is not None:
+                sized_kw["costs"] = np.asarray(costs)
+            with TraceAnnotation("repro.run.init"):
+                carry = pd.init(
+                    int(catalog_size),
+                    int(capacity),
+                    seed=seed,
+                    eta=eta,
+                    horizon=int(horizon) if horizon is not None else t_used,
+                    n_slots=n_slots,
+                    **sized_kw,
+                    **init_kw,
+                )
+            if eta is not None:
+                extras["eta"] = float(eta)
+        elif (
+            eta is not None
+            or horizon is not None
+            or n_slots is not None
+            or seed != 0
+            or costs is not None
+            or any(v is not None for v in init_kw.values())
+        ):
+            # a resumed run takes every policy parameter from the carry; a
+            # silently-ignored eta or seed would mislabel sweep results
+            # (sizes= stays allowed: it only drives host-side byte accounting)
+            raise ValueError(
+                "run(carry=...) resumes with the carry's parameters; do not "
+                "pass seed/eta/horizon/n_slots/costs/init kwargs alongside a "
+                "carry"
             )
-        sized_kw = {}
+        with TraceAnnotation("repro.run.lookup"):
+            compiled = _compiled(_scan_jit(pd.step), carry, chunks)
+        t0 = time.perf_counter()
+        with TraceAnnotation("repro.run.dispatch"):
+            carry, out = compiled(carry, chunks)
+        if block:
+            with TraceAnnotation("repro.run.wait"):
+                jax.block_until_ready((carry, out))
+        wall = time.perf_counter() - t0
+        opt = 0.0
+        if track_opt and capacity is not None:
+            with TraceAnnotation("repro.run.opt"):
+                opt = float(best_static_hits(trace_used, int(capacity)))
+        bytes_total = 0.0
         if sizes is not None:
-            sized_kw["sizes"] = np.asarray(sizes)
-        if costs is not None:
-            sized_kw["costs"] = np.asarray(costs)
-        carry = pd.init(
-            int(catalog_size),
-            int(capacity),
-            seed=seed,
-            eta=eta,
-            horizon=int(horizon) if horizon is not None else t_used,
-            n_slots=n_slots,
-            **sized_kw,
-            **init_kw,
-        )
-        if eta is not None:
-            extras["eta"] = float(eta)
-    elif (
-        eta is not None
-        or horizon is not None
-        or n_slots is not None
-        or seed != 0
-        or costs is not None
-        or any(v is not None for v in init_kw.values())
-    ):
-        # a resumed run takes every policy parameter from the carry; a
-        # silently-ignored eta or seed would mislabel sweep results
-        # (sizes= stays allowed: it only drives host-side byte accounting)
-        raise ValueError(
-            "run(carry=...) resumes with the carry's parameters; do not "
-            "pass seed/eta/horizon/n_slots/costs/init kwargs alongside a "
-            "carry"
-        )
-    compiled = _compiled(_scan_jit(pd.step), carry, chunks)
-    t0 = time.perf_counter()
-    carry, out = compiled(carry, chunks)
-    if block:
-        jax.block_until_ready((carry, out))
-    wall = time.perf_counter() - t0
-    opt = (
-        float(best_static_hits(trace_used, int(capacity)))
-        if (track_opt and capacity is not None)
-        else 0.0
-    )
-    bytes_total = 0.0
-    if sizes is not None:
-        bytes_total = float(
-            np.sum(np.asarray(sizes, np.float64)[trace_used])
-        )
-    if block:
-        reward = np.asarray(out.reward, np.float64)
-        hits = np.asarray(out.hits, np.int64)
-        aux = np.asarray(out.aux, np.float64)
-        occupancy = np.asarray(out.occupancy, np.float64)
-        byte_hits = (
-            np.asarray(out.byte_hits, np.float64)
-            if out.byte_hits is not None
-            else None
-        )
-    else:
-        # in-flight device arrays: np.asarray here would silently block
-        reward, hits, aux, occupancy = (
-            out.reward, out.hits, out.aux, out.occupancy
-        )
-        byte_hits = out.byte_hits
+            bytes_total = float(
+                np.sum(np.asarray(sizes, np.float64)[trace_used])
+            )
+        if block:
+            with TraceAnnotation("repro.run.readback"):
+                reward = np.asarray(out.reward, np.float64)
+                hits = np.asarray(out.hits, np.int64)
+                aux = np.asarray(out.aux, np.float64)
+                occupancy = np.asarray(out.occupancy, np.float64)
+                byte_hits = (
+                    np.asarray(out.byte_hits, np.float64)
+                    if out.byte_hits is not None
+                    else None
+                )
+        else:
+            # in-flight device arrays: np.asarray here would silently block
+            reward, hits, aux, occupancy = (
+                out.reward, out.hits, out.aux, out.occupancy
+            )
+            byte_hits = out.byte_hits
     return RunResult(
         name=name or pd.name,
         kind=pd.kind,

@@ -21,7 +21,7 @@ origin cache's request stream.
 """
 
 # the ingest thread is the sole writer of the stream-position counters
-# reprolint: thread-owned(t_ingested, ingest_seconds, t_dropped)
+# reprolint: thread-owned(t_ingested, t_dropped)
 
 from __future__ import annotations
 
@@ -399,9 +399,9 @@ def run_fleet(
 class _FleetState:
     """Accumulators shared by the sync and async fleet-stream drivers.
 
-    The ingest-side counters (``t_ingested``, ``ingest_seconds``,
-    ``t_dropped``) are written only by whichever thread runs the segment
-    assembly; the replay-side accumulators only by the main thread."""
+    The ingest-side counters (``t_ingested``, ``t_dropped``) are written
+    only by whichever thread runs the segment assembly; the replay-side
+    accumulators only by the main thread."""
 
     def __init__(self):
         self.reward: list = []
@@ -413,9 +413,6 @@ class _FleetState:
         self.t_used = 0  # per tenant
         self.t_ingested = 0  # across the fleet
         self.t_dropped = 0
-        self.ingest_seconds = 0.0
-        self.device_seconds = 0.0
-        self.host_seconds = 0.0
         self.counts: Optional[np.ndarray] = None  # (E, N) when track_opt
         self.bytes_total: Optional[np.ndarray] = None
 
@@ -441,17 +438,13 @@ def _assemble_fleet_segments(
     done = [False] * n
 
     def _pull(e: int) -> None:
-        t0 = time.perf_counter()
         try:
             chunk = next(its[e])
         except StopIteration:
-            st.ingest_seconds += time.perf_counter() - t0
             done[e] = True
             return
         except Exception as err:  # reprolint: allow(broad-except) wrapped as _SourceError
-            st.ingest_seconds += time.perf_counter() - t0
             raise _stream._SourceError(err) from err
-        st.ingest_seconds += time.perf_counter() - t0
         chunk = np.asarray(chunk, dtype=np.int64).ravel()
         if chunk.size == 0:
             return
@@ -605,12 +598,10 @@ def run_fleet_stream(
         chunks = jnp.asarray(
             seg.reshape(n_tenants, -1, window), jnp.int32
         )
-        t0 = time.perf_counter()
         compiled = api._compiled(jitted, stacked, chunks)
         stacked, out = compiled(stacked, chunks)
         if block:
             jax.block_until_ready(out)
-        st.device_seconds += time.perf_counter() - t0
         return out, seg.shape[1]
 
     def _host_pass(seg: np.ndarray) -> None:
@@ -618,7 +609,6 @@ def run_fleet_stream(
         overlaps the device scan in the async pipeline)."""
         if st.counts is None and sizes_np is None:
             return
-        t0 = time.perf_counter()
         for e in range(n_tenants):
             if st.counts is not None:
                 st.counts[e] += np.bincount(
@@ -626,14 +616,10 @@ def run_fleet_stream(
                 )
             if sizes_np is not None:
                 st.bytes_total[e] += float(sizes_np[seg[e]].sum())
-        st.host_seconds += time.perf_counter() - t0
 
     def _consume(item) -> None:
         out, t_seg = item
-        t0 = time.perf_counter()
         jax.block_until_ready((out.reward, out.hits, out.aux, out.occupancy))
-        st.device_seconds += time.perf_counter() - t0
-        t0 = time.perf_counter()
         st.reward.append(np.asarray(out.reward, np.float64))
         st.hits.append(np.asarray(out.hits, np.int64))
         st.aux.append(np.asarray(out.aux, np.float64))
@@ -642,7 +628,6 @@ def run_fleet_stream(
             st.byte_hits.append(np.asarray(out.byte_hits, np.float64))
         st.n_segments += 1
         st.t_used += t_seg
-        st.host_seconds += time.perf_counter() - t0
 
     def _result() -> FleetResult:
         if st.counts is not None:

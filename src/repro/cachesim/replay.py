@@ -161,19 +161,23 @@ def _make_ogb_step(
     def step(eta, p, cap, carry, xs):
         f, tau_prev, counts_tot = carry
         ids, u = xs
-        reward, hits, occ = sample_chunk_metrics(
-            sample, madow_capacity, f, ids, p, u
-        )
+        # named scopes (ogb/<phase>) let a device trace split the step
+        with jax.named_scope("ogb/sample"):
+            reward, hits, occ = sample_chunk_metrics(
+                sample, madow_capacity, f, ids, p, u
+            )
         # gradient step as a B-element scatter-add (duplicates accumulate);
         # avoids materializing a dense (N,) counts histogram per chunk
-        y = f.at[ids].add(eta)
-        if projection == "warm":
-            hi = warm_bracket_hi(eta * jnp.float32(ids.shape[0]))
-            f_new, tau = capped_simplex_project_warm(
-                y, cap, jnp.float32(0.0), hi, tau_prev, sweeps
-            )
-        else:
-            f_new, tau = capped_simplex_project(y, cap, iters)
+        with jax.named_scope("ogb/gradient"):
+            y = f.at[ids].add(eta)
+        with jax.named_scope("ogb/project"):
+            if projection == "warm":
+                hi = warm_bracket_hi(eta * jnp.float32(ids.shape[0]))
+                f_new, tau = capped_simplex_project_warm(
+                    y, cap, jnp.float32(0.0), hi, tau_prev, sweeps
+                )
+            else:
+                f_new, tau = capped_simplex_project(y, cap, iters)
         if track_opt:
             counts_tot = counts_tot.at[ids].add(1.0)
         return (

@@ -153,14 +153,13 @@ class StreamResult(RunResult):
     ``opt_hits``.
 
     **Timing split:** ``wall_seconds`` stays the total wall clock of the
-    stream (back-compat).  The component clocks attribute it:
-    ``ingest_seconds`` is time spent waiting on the chunk source,
-    ``device_seconds`` is dispatch plus time blocked on device results,
-    and ``host_seconds`` is the segment re-batching + dynamic-OPT
-    accounting.  On the synchronous path (``prefetch=0``) the components
-    sum to roughly ``wall_seconds``; on the async pipeline they *overlap*,
-    so their sum can exceed the wall clock — that surplus is the measured
-    overlap win.
+    stream (back-compat).  ``ingest_seconds`` is time spent inside the
+    chunk source and ``host_seconds`` the main thread's dynamic-OPT
+    accounting and folding of results.  On the synchronous path
+    (``prefetch=0``) they sum to at most ``wall_seconds``; on the async
+    pipeline they overlap the device.  The dispatch and the wait for the
+    device are the ``repro.run.dispatch`` and ``repro.stream.wait_device``
+    spans of a profiler trace.
     """
 
     dyn_opt_hits: Optional[np.ndarray] = None  # (K,) per-window OPT hits
@@ -168,8 +167,7 @@ class StreamResult(RunResult):
     n_segments: int = 0  # device dispatches the stream took
     t_dropped: int = 0  # trailing requests short of one window, not replayed
     ingest_seconds: float = 0.0  # time waiting on the chunk source
-    device_seconds: float = 0.0  # dispatch + time blocked on device results
-    host_seconds: float = 0.0  # re-batching + dynamic-OPT host accounting
+    host_seconds: float = 0.0  # dynamic-OPT accounting + folding results
     prefetch: int = 0  # pipeline depth the stream ran with (0 = synchronous)
 
     @property

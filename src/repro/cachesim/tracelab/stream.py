@@ -33,8 +33,17 @@ the stream runs as a pipeline instead:
 The pipeline is **bit-exact** with the synchronous path — same segment
 re-batching, same carry chain, same dynamic-OPT windows — only the
 :class:`~repro.cachesim.results.StreamResult` timing split
-(``ingest_seconds`` / ``device_seconds`` / ``host_seconds``) tells them
-apart.  ``prefetch=0`` falls back to the fully synchronous loop.
+(``ingest_seconds`` / ``host_seconds``) tells them apart.  ``prefetch=0``
+falls back to the fully synchronous loop.
+
+Under ``jax.profiler.trace`` the call shows as one ``repro.stream`` span
+on the main thread, holding ``repro.stream.queue_wait`` (waiting for the
+ingest thread; argument ``depth``, the queue's depth on entry),
+``repro.stream.dyn_opt``, ``repro.stream.consume`` (with
+``repro.stream.wait_device`` nested) and each segment's ``repro.run.*``
+spans; the thread that assembles segments records
+``repro.stream.source`` (time inside the chunk source) and
+``repro.stream.validate`` (the id-range check and re-batching).
 
 When the chunk source *raises* mid-stream, the pipeline degrades
 gracefully: in-flight device work is drained, accumulated results are
@@ -61,6 +70,7 @@ from typing import Any, Iterable, Iterator, Optional, Union
 import numpy as np
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.cachesim import api
 from repro.cachesim.results import StreamResult
@@ -153,7 +163,6 @@ class _StreamState:
         self.t_dropped = 0
         self.extras: dict = {}
         self.ingest_seconds = 0.0
-        self.device_seconds = 0.0
         self.host_seconds = 0.0
 
 
@@ -176,39 +185,46 @@ def _assemble_segments(
     buf: list = []
     buffered = 0
     while True:
-        t0 = time.perf_counter()
-        try:
-            chunk = next(it)
-        except StopIteration:
+        with TraceAnnotation("repro.stream.source"):
+            t0 = time.perf_counter()
+            try:
+                chunk = next(it)
+            except StopIteration:
+                st.ingest_seconds += time.perf_counter() - t0
+                break
+            except Exception as e:  # the source failed, not us
+                st.ingest_seconds += time.perf_counter() - t0
+                raise _SourceError(e) from e
             st.ingest_seconds += time.perf_counter() - t0
-            break
-        except Exception as e:  # the source failed, not us
-            st.ingest_seconds += time.perf_counter() - t0
-            raise _SourceError(e) from e
-        st.ingest_seconds += time.perf_counter() - t0
-        chunk = np.asarray(chunk, dtype=np.int64).ravel()
-        if chunk.size == 0:
-            continue
-        if catalog_size is not None and not (
-            0 <= int(chunk.min()) and int(chunk.max()) < catalog_size
-        ):
-            # an out-of-range dense id would be silently clamped by the
-            # device gather (aliasing item N-1) — corrupt results, no error
-            raise ValueError(
-                f"stream ids must be dense in [0, {catalog_size}): got "
-                f"[{int(chunk.min())}, {int(chunk.max())}] — route raw "
-                "traces through CatalogRemap (with max_items=catalog_size) "
-                "first"
-            )
-        st.t_ingested += chunk.size
-        buf.append(chunk)
-        buffered += chunk.size
-        while buffered >= segment_len:
-            merged = np.concatenate(buf) if len(buf) > 1 else buf[0]
-            yield merged[:segment_len]
-            rest = merged[segment_len:]
-            buf = [rest] if rest.size else []
-            buffered = rest.size
+        # full segments are cut inside the span and yielded after it, so
+        # the span never stays open while the consumer holds the generator
+        ready = []
+        with TraceAnnotation("repro.stream.validate"):
+            chunk = np.asarray(chunk, dtype=np.int64).ravel()
+            if chunk.size == 0:
+                continue
+            if catalog_size is not None and not (
+                0 <= int(chunk.min()) and int(chunk.max()) < catalog_size
+            ):
+                # an out-of-range dense id would be silently clamped by the
+                # device gather (aliasing item N-1) — corrupt results, no
+                # error
+                raise ValueError(
+                    f"stream ids must be dense in [0, {catalog_size}): got "
+                    f"[{int(chunk.min())}, {int(chunk.max())}] — route raw "
+                    "traces through CatalogRemap (with "
+                    "max_items=catalog_size) first"
+                )
+            st.t_ingested += chunk.size
+            buf.append(chunk)
+            buffered += chunk.size
+            while buffered >= segment_len:
+                merged = np.concatenate(buf) if len(buf) > 1 else buf[0]
+                ready.append(merged[:segment_len])
+                rest = merged[segment_len:]
+                buf = [rest] if rest.size else []
+                buffered = rest.size
+        yield from ready
     # tail: whole windows replay as one final (differently shaped) segment
     if buffered:
         merged = np.concatenate(buf) if len(buf) > 1 else buf[0]
@@ -353,7 +369,6 @@ def run_stream(
         else:
             res = api.run(pd, seg, capacity=capacity, carry=carry, **run_kw)
         carry = res.carry
-        st.device_seconds += res.wall_seconds
         return res
 
     def _host_pass(seg: np.ndarray):
@@ -363,41 +378,42 @@ def run_stream(
         if opt_window is None:
             return
         t0 = time.perf_counter()
-        st.opt_buf.append(seg)
-        st.opt_buffered += len(seg)
-        while st.opt_buffered >= opt_window:
-            merged = (
-                np.concatenate(st.opt_buf)
-                if len(st.opt_buf) > 1
-                else st.opt_buf[0]
-            )
-            st.dyn_opt.append(
-                float(best_static_hits(merged[:opt_window], int(capacity)))
-            )
-            rest = merged[opt_window:]
-            st.opt_buf[:] = [rest] if rest.size else []
-            st.opt_buffered = rest.size
+        with TraceAnnotation("repro.stream.dyn_opt"):
+            st.opt_buf.append(seg)
+            st.opt_buffered += len(seg)
+            while st.opt_buffered >= opt_window:
+                merged = (
+                    np.concatenate(st.opt_buf)
+                    if len(st.opt_buf) > 1
+                    else st.opt_buf[0]
+                )
+                st.dyn_opt.append(float(
+                    best_static_hits(merged[:opt_window], int(capacity))
+                ))
+                rest = merged[opt_window:]
+                st.opt_buf[:] = [rest] if rest.size else []
+                st.opt_buffered = rest.size
         st.host_seconds += time.perf_counter() - t0
 
     def _consume(res):
         """Fold one segment's (possibly in-flight) results into the
         accumulators — the only place the pipeline blocks on the device."""
-        t0 = time.perf_counter()
-        jax.block_until_ready(
-            (res.reward, res.hits, res.aux, res.occupancy)
-        )
-        st.device_seconds += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        st.reward.append(np.asarray(res.reward, np.float64))
-        st.hits.append(np.asarray(res.hits, np.int64))
-        st.aux.append(np.asarray(res.aux, np.float64))
-        st.occupancy.append(np.asarray(res.occupancy, np.float64))
-        if res.byte_hits is not None:
-            st.byte_hits.append(np.asarray(res.byte_hits, np.float64))
-        st.bytes_total += res.bytes_total
-        st.n_segments += 1
-        st.t_used += res.T
-        st.host_seconds += time.perf_counter() - t0
+        with TraceAnnotation("repro.stream.consume"):
+            with TraceAnnotation("repro.stream.wait_device"):
+                jax.block_until_ready(
+                    (res.reward, res.hits, res.aux, res.occupancy)
+                )
+            t0 = time.perf_counter()
+            st.reward.append(np.asarray(res.reward, np.float64))
+            st.hits.append(np.asarray(res.hits, np.int64))
+            st.aux.append(np.asarray(res.aux, np.float64))
+            st.occupancy.append(np.asarray(res.occupancy, np.float64))
+            if res.byte_hits is not None:
+                st.byte_hits.append(np.asarray(res.byte_hits, np.float64))
+            st.bytes_total += res.bytes_total
+            st.n_segments += 1
+            st.t_used += res.T
+            st.host_seconds += time.perf_counter() - t0
 
     def _flush_dyn_opt_tail():
         """The replayed remainder shorter than one opt_window still gets a
@@ -406,14 +422,15 @@ def run_stream(
         if opt_window is None or not st.opt_buffered:
             return
         t0 = time.perf_counter()
-        merged = (
-            np.concatenate(st.opt_buf)
-            if len(st.opt_buf) > 1
-            else st.opt_buf[0]
-        )
-        st.dyn_opt.append(float(best_static_hits(merged, int(capacity))))
-        st.opt_buf.clear()
-        st.opt_buffered = 0
+        with TraceAnnotation("repro.stream.dyn_opt"):
+            merged = (
+                np.concatenate(st.opt_buf)
+                if len(st.opt_buf) > 1
+                else st.opt_buf[0]
+            )
+            st.dyn_opt.append(float(best_static_hits(merged, int(capacity))))
+            st.opt_buf.clear()
+            st.opt_buffered = 0
         st.host_seconds += time.perf_counter() - t0
 
     def _result() -> StreamResult:
@@ -446,7 +463,6 @@ def run_stream(
             n_segments=st.n_segments,
             t_dropped=st.t_dropped,
             ingest_seconds=st.ingest_seconds,
-            device_seconds=st.device_seconds,
             host_seconds=st.host_seconds,
             prefetch=prefetch,
         )
@@ -468,81 +484,85 @@ def run_stream(
             partial=partial,
         )
 
-    if prefetch == 0:
-        # ---- synchronous fallback: load, step, repeat --------------------
-        segs = _assemble_segments(
-            chunks, segment_len, window, catalog_size, st
-        )
-        while True:
-            try:
-                seg = next(segs)
-            except StopIteration:
-                break
-            except _SourceError as e:
-                raise _fault(e) from e.cause
-            res = _dispatch(seg, block=True)
-            _host_pass(seg)
-            _consume(res)
-    else:
-        # ---- async double-buffered pipeline ------------------------------
-        q: "queue.Queue" = queue.Queue(maxsize=prefetch)
-        stop = threading.Event()
-
-        def _put(item) -> bool:
-            # bounded put that aborts when the consumer has bailed, so the
-            # ingest thread can never hang on a dead pipeline
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.05)
-                    return True
-                except queue.Full:
-                    continue
-            return False
-
-        def _ingest():
-            try:
-                for seg in _assemble_segments(
-                    chunks, segment_len, window, catalog_size, st
-                ):
-                    if not _put(seg):
-                        return
-                _put(_DONE)
-            except BaseException as e:  # reprolint: allow(broad-except) forwarded; classified by main
-                _put(e)  # (source fault vs validation error)
-
-        worker = threading.Thread(
-            target=_ingest, name="run_stream-ingest", daemon=True
-        )
-        worker.start()
-        pending: deque = deque()  # dispatched, not yet consumed
-        try:
+    with TraceAnnotation("repro.stream"):
+        if prefetch == 0:
+            # ---- synchronous fallback: load, step, repeat ----------------
+            segs = _assemble_segments(
+                chunks, segment_len, window, catalog_size, st
+            )
             while True:
-                item = q.get()
-                if item is _DONE:
+                try:
+                    seg = next(segs)
+                except StopIteration:
                     break
-                if isinstance(item, _SourceError):
-                    raise _fault(item, pending) from item.cause
-                if isinstance(item, BaseException):
-                    for res in pending:  # drain before re-raising
-                        _consume(res)
-                    pending.clear()
-                    raise item
-                res = _dispatch(item, block=False)
-                pending.append(res)
-                _host_pass(item)  # overlaps the device scan just dispatched
-                while len(pending) > prefetch:
+                except _SourceError as e:
+                    raise _fault(e) from e.cause
+                res = _dispatch(seg, block=True)
+                _host_pass(seg)
+                _consume(res)
+        else:
+            # ---- async double-buffered pipeline --------------------------
+            q: "queue.Queue" = queue.Queue(maxsize=prefetch)
+            stop = threading.Event()
+
+            def _put(item) -> bool:
+                # bounded put that aborts when the consumer has bailed, so the
+                # ingest thread can never hang on a dead pipeline
+                while not stop.is_set():
+                    try:
+                        q.put(item, timeout=0.05)
+                        return True
+                    except queue.Full:
+                        continue
+                return False
+
+            def _ingest():
+                try:
+                    for seg in _assemble_segments(
+                        chunks, segment_len, window, catalog_size, st
+                    ):
+                        if not _put(seg):
+                            return
+                    _put(_DONE)
+                except BaseException as e:  # reprolint: allow(broad-except) forwarded; classified by main
+                    _put(e)  # (source fault vs validation error)
+
+            worker = threading.Thread(
+                target=_ingest, name="run_stream-ingest", daemon=True
+            )
+            worker.start()
+            pending: deque = deque()  # dispatched, not yet consumed
+            try:
+                while True:
+                    with TraceAnnotation("repro.stream.queue_wait",
+                                         depth=q.qsize()):
+                        item = q.get()
+                    if item is _DONE:
+                        break
+                    if isinstance(item, _SourceError):
+                        raise _fault(item, pending) from item.cause
+                    if isinstance(item, BaseException):
+                        for res in pending:  # drain before re-raising
+                            _consume(res)
+                        pending.clear()
+                        raise item
+                    res = _dispatch(item, block=False)
+                    pending.append(res)
+                    # overlaps the device scan just dispatched
+                    _host_pass(item)
+                    while len(pending) > prefetch:
+                        _consume(pending.popleft())
+                while pending:
                     _consume(pending.popleft())
-            while pending:
-                _consume(pending.popleft())
-        finally:
-            stop.set()
-            worker.join(timeout=5.0)
+            finally:
+                stop.set()
+                worker.join(timeout=5.0)
 
-    _flush_dyn_opt_tail()
+        _flush_dyn_opt_tail()
 
-    if st.t_used == 0:
-        raise ValueError(
-            f"stream shorter than one window ({st.t_dropped} < {window})"
-        )
+        if st.t_used == 0:
+            raise ValueError(
+                f"stream shorter than one window ({st.t_dropped} < {window})"
+            )
 
-    return _result()
+        return _result()

@@ -776,84 +776,97 @@ def make_ogb_tree_chunk(catalog_size: int, v: int, radix: int, sample: str,
         ycnt, ysum, dcnt = carry.ycnt, carry.ysum, carry.dcnt
         lanes = jnp.arange(b, dtype=jnp.int32)
 
+        # each phase runs under a named scope (ogb_tree/<phase>), so the
+        # ops of a device trace name the phase they belong to
         # --- metrics at the pre-update state (OCO order), O(B) gathers ---
-        fi = jnp.clip(y[ids] - rho, 0.0, 1.0)
-        reward = jnp.sum(fi)
-        if poisson:
-            hits = jnp.sum((fi >= p[ids]).astype(jnp.int32))
-            # occupancy #{y - p >= rho} from the d-tree: suffix count above
-            # rho's bucket (quantized at the boundary bucket)
-            dtot = pt.tree_total(dcnt, v, radix)
-            occ = dtot - pt.tree_prefix(
-                dcnt, v, radix, _ogb_bucket(rho, wv, v)[None]
-            )[0]
-        else:
-            hits = jnp.zeros((), jnp.int32)
-            occ = cap
+        with jax.named_scope("ogb_tree/metrics"):
+            fi = jnp.clip(y[ids] - rho, 0.0, 1.0)
+            reward = jnp.sum(fi)
+            if poisson:
+                hits = jnp.sum((fi >= p[ids]).astype(jnp.int32))
+                # occupancy #{y - p >= rho} from the d-tree: suffix count
+                # above rho's bucket (quantized at the boundary bucket)
+                dtot = pt.tree_total(dcnt, v, radix)
+                occ = dtot - pt.tree_prefix(
+                    dcnt, v, radix, _ogb_bucket(rho, wv, v)[None]
+                )[0]
+            else:
+                hits = jnp.zeros((), jnp.int32)
+                occ = cap
 
         # --- first-occurrence mask (dedup without sorting) ---
-        a = scratch.at[ids].min(lanes)
-        first = a[ids] == lanes
-        scratch = a.at[ids].set(_I32_MAX)  # restore
+        with jax.named_scope("ogb_tree/dedup"):
+            a = scratch.at[ids].min(lanes)
+            first = a[ids] == lanes
+            scratch = a.at[ids].set(_I32_MAX)  # restore
 
         # --- gradient step: clip touched items, add eta per request ---
-        yold = y[ids]
-        y = y.at[ids].min(1.0 + rho).at[ids].max(rho)
-        y = y.at[ids].add(eta)
-        ynew = y[ids]
+        with jax.named_scope("ogb_tree/gradient"):
+            yold = y[ids]
+            y = y.at[ids].min(1.0 + rho).at[ids].max(rho)
+            y = y.at[ids].add(eta)
+            ynew = y[ids]
 
         # --- move touched items between buckets (one per distinct item) ---
-        bo = jnp.where(first, _ogb_bucket(yold, wv, v), -1)
-        bn = jnp.where(first, _ogb_bucket(ynew, wv, v), -1)
-        didx = jnp.concatenate([bo, bn])
-        ones = jnp.ones(b, jnp.float32)
-        ycnt = pt.tree_update(ycnt, v, radix, didx,
-                              jnp.concatenate([-ones, ones]))
-        ysum = pt.tree_update(
-            ysum, v, radix, didx,
-            jnp.concatenate([
-                jnp.where(first, -yold, 0.0), jnp.where(first, ynew, 0.0)
-            ]),
-        )
-        if poisson:
-            do = jnp.where(first, _ogb_bucket(yold - p[ids], wv, v), -1)
-            dn = jnp.where(first, _ogb_bucket(ynew - p[ids], wv, v), -1)
-            dcnt = pt.tree_update(dcnt, v, radix,
-                                  jnp.concatenate([do, dn]),
+        with jax.named_scope("ogb_tree/update"):
+            bo = jnp.where(first, _ogb_bucket(yold, wv, v), -1)
+            bn = jnp.where(first, _ogb_bucket(ynew, wv, v), -1)
+            didx = jnp.concatenate([bo, bn])
+            ones = jnp.ones(b, jnp.float32)
+            ycnt = pt.tree_update(ycnt, v, radix, didx,
                                   jnp.concatenate([-ones, ones]))
+            ysum = pt.tree_update(
+                ysum, v, radix, didx,
+                jnp.concatenate([
+                    jnp.where(first, -yold, 0.0),
+                    jnp.where(first, ynew, 0.0),
+                ]),
+            )
+            if poisson:
+                do = jnp.where(first, _ogb_bucket(yold - p[ids], wv, v), -1)
+                dn = jnp.where(first, _ogb_bucket(ynew - p[ids], wv, v), -1)
+                dcnt = pt.tree_update(dcnt, v, radix,
+                                      jnp.concatenate([do, dn]),
+                                      jnp.concatenate([-ones, ones]))
 
         # --- scalar threshold solve: bisect on the warm bracket ---
-        total = pt.tree_total(ycnt, v, radix)
-        # rho* - rho <= eta*B (chained-projection bound); the 4w floor keeps
-        # the bracket wider than the mass quantization when eta*B < w
-        hi0 = rho + jnp.maximum(eta * jnp.float32(b), 4.0 * wv)
+        with jax.named_scope("ogb_tree/solve"):
+            total = pt.tree_total(ycnt, v, radix)
+            # rho* - rho <= eta*B (chained-projection bound); the 4w floor
+            # keeps the bracket wider than the mass quantization when
+            # eta*B < w
+            hi0 = rho + jnp.maximum(eta * jnp.float32(b), 4.0 * wv)
 
-        def bis(_, lohi):
-            lo, hi = lohi
-            mid = 0.5 * (lo + hi)
-            m = mass_at(ycnt, ysum, wv, total, mid)
-            return jnp.where(m >= cap, mid, lo), jnp.where(m >= cap, hi, mid)
+            def bis(_, lohi):
+                lo, hi = lohi
+                mid = 0.5 * (lo + hi)
+                m = mass_at(ycnt, ysum, wv, total, mid)
+                return (jnp.where(m >= cap, mid, lo),
+                        jnp.where(m >= cap, hi, mid))
 
-        rho_new, _ = jax.lax.fori_loop(0, iters, bis, (rho, hi0))
+            rho_new, _ = jax.lax.fori_loop(0, iters, bis, (rho, hi0))
 
         # --- re-anchor when the next chunk could outgrow the value grid ---
         gridtop = wv * jnp.float32(v) - 1.0
 
         def reanchor(args):
-            y, rho_new, ycnt, ysum, dcnt = args
-            y = jnp.clip(y - rho_new, 0.0, 1.0)
-            by = _ogb_bucket(y, wv, v)
-            onesn = jnp.ones_like(y)
-            cl = jnp.zeros(v, jnp.float32).at[by].add(onesn)
-            sl = jnp.zeros(v, jnp.float32).at[by].add(y)
-            ycnt = pt.tree_build(cl, radix)
-            ysum = pt.tree_build(sl, radix)
-            if poisson:
-                dl = jnp.zeros(v, jnp.float32).at[
-                    _ogb_bucket(y - p, wv, v)
-                ].add(onesn)
-                dcnt = pt.tree_build(dl, radix)
-            return y, jnp.float32(0.0), ycnt, ysum, dcnt
+            # scoped inside the branch: the scope's time is zero on every
+            # chunk that does not re-anchor
+            with jax.named_scope("ogb_tree/reanchor"):
+                y, rho_new, ycnt, ysum, dcnt = args
+                y = jnp.clip(y - rho_new, 0.0, 1.0)
+                by = _ogb_bucket(y, wv, v)
+                onesn = jnp.ones_like(y)
+                cl = jnp.zeros(v, jnp.float32).at[by].add(onesn)
+                sl = jnp.zeros(v, jnp.float32).at[by].add(y)
+                ycnt = pt.tree_build(cl, radix)
+                ysum = pt.tree_build(sl, radix)
+                if poisson:
+                    dl = jnp.zeros(v, jnp.float32).at[
+                        _ogb_bucket(y - p, wv, v)
+                    ].add(onesn)
+                    dcnt = pt.tree_build(dl, radix)
+                return y, jnp.float32(0.0), ycnt, ysum, dcnt
 
         y, rho_out, ycnt, ysum, dcnt = jax.lax.cond(
             1.0 + rho_new + eta * jnp.float32(b) >= gridtop - wv,

@@ -192,11 +192,10 @@ def test_timing_split_components():
             horizon=T, segment_len=2048, opt_window=640, prefetch=prefetch,
         )
         assert sr.wall_seconds > 0
-        assert sr.device_seconds > 0
         assert sr.ingest_seconds >= 0 and sr.host_seconds >= 0
         if prefetch == 0:
-            # synchronous: the components partition the wall clock
-            total = sr.ingest_seconds + sr.device_seconds + sr.host_seconds
+            # synchronous: the components fit inside the wall clock
+            total = sr.ingest_seconds + sr.host_seconds
             assert total <= sr.wall_seconds + 0.05
 
 
