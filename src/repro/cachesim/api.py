@@ -770,7 +770,10 @@ def _ogb_tree_def(
     Same gradient step and hit accounting as ``ogb``; the per-chunk
     capped-simplex projection is replaced by a scalar threshold solve over
     a V-bucket histogram of the accumulated values, so per-chunk work no
-    longer scales with the catalog.  Hit ratios track the dense ``ogb``
+    longer scales with the catalog.  ``iters`` is the solve's resolution,
+    in halvings of its warm bracket; a batched K-ary search resolves
+    log2(K) of them per round (K = ``OGB_TREE_SPLIT``), with no loop
+    inside the chunk.  Hit ratios track the dense ``ogb``
     within the histogram quantization (see the differential test); use
     ``ogb`` when bit-exact projections matter.  ``sample`` is limited to
     ``"poisson"``/``"none"`` — Madow needs the full fractional vector.

@@ -637,8 +637,14 @@ def make_gds_tree_chunk(catalog_size: int, k: int,
 OGB_TREE_BUCKETS = 65536
 #: radix of the bucket count/sum trees
 OGB_TREE_RADIX = 64
-#: bisection iterations of the per-chunk threshold solve
+#: halvings of the per-chunk threshold solve's bracket (its resolution is
+#: the bracket over 2**OGB_TREE_ITERS); each round resolves log2 of
+#: OGB_TREE_SPLIT of them
 OGB_TREE_ITERS = 30
+#: sub-intervals per round of the threshold solve: a round evaluates the
+#: mass at all OGB_TREE_SPLIT - 1 interior points at once, so 30 halvings
+#: take six dependent rounds
+OGB_TREE_SPLIT = 32
 #: grid headroom factor: the value grid spans ~2*GAIN chunk-updates of rho
 #: growth before a re-anchor pass is needed
 OGB_TREE_GAIN = 8.0
@@ -728,6 +734,89 @@ def init_ogb_tree_carry(
     )
 
 
+def _sibling_rows(ycnt, ysum, v: int, radix: int):
+    """Each level of the count and sum trees as rows of sibling groups,
+    ``(groups, 2 * radix)``: a group of the count tree beside the same
+    group of the sum tree, so one row gather per level reads both."""
+    rows = []
+    for off, size in zip(pt.tree_offsets(v, radix), pt.tree_sizes(v, radix)):
+        groups = -(-size // radix)
+        pad = (0, groups * radix - size)
+        rows.append(jnp.concatenate(
+            [jnp.pad(tree[off:off + size], pad).reshape(groups, radix)
+             for tree in (ycnt, ysum)], axis=1))
+    return rows
+
+
+def _ogb_tree_mass(rows, total, wv, t, v: int, radix: int):
+    """sum_b cnt_b * clip(mean_b - t, 0, 1) at each threshold of ``t``
+    (any shape) via O(log V) tree reads.
+
+    The prefix sums are :func:`repro.kernels.prefix_tree.ops.tree_prefix`'s
+    (per level, the query ancestor's sibling group masked to its left
+    part), but each group is read as one row of ``rows``
+    (:func:`_sibling_rows`) rather than as ``radix`` scalar gathers: on
+    the TPU a gather costs about the same per row as per element."""
+    sh = radix.bit_length() - 1
+    lane = jnp.arange(radix, dtype=jnp.int32)
+    k0, k1 = _ogb_bucket(t, wv, v), _ogb_bucket(t + 1.0, wv, v)
+    node = jnp.stack([k0, k1])
+    q = None
+    for lvl, r in enumerate(rows):
+        grp = r[0] if r.shape[0] == 1 else r[node >> sh]
+        vals = grp.reshape(grp.shape[:-1] + (2, radix))
+        lim = (node & (radix - 1))[..., None, None]
+        if lvl == 0:
+            # the bucket itself: (count, sum) of leaves k0 and k1
+            leaf = jnp.sum(jnp.where(lane == lim, vals, 0.0), axis=-1)
+        within = lane <= lim if lvl == 0 else lane < lim
+        part = jnp.sum(jnp.where(within, vals, 0.0), axis=-1)
+        q = part if q is None else q + part
+        node = node >> sh
+    qc, qs = q[..., 0], q[..., 1]
+    cb, sb = leaf[..., 0], leaf[..., 1]
+    # buckets above k1 are entirely past t+1: full mass
+    above = total - qc[1]
+    # buckets strictly between k0 and k1 lie in the linear clip region
+    mid_c = qc[1] - cb[1] - qc[0]
+    mid_s = qs[1] - sb[1] - qs[0]
+    mid = mid_s - t * mid_c
+    # boundary buckets: mean-clip approximation
+    mean = jnp.where(cb > 0, sb / jnp.maximum(cb, 1.0), 0.0)
+    bnd = cb * jnp.clip(mean - t, 0.0, 1.0)
+    return above + mid + bnd[0] + jnp.where(k1 > k0, bnd[1], 0.0)
+
+
+def _ogb_tree_solve(ycnt, ysum, wv, total, cap, lo, hi, v: int, radix: int,
+                    iters: int):
+    """Threshold solve on the bracket ``[lo, hi]`` with ``mass(lo) >= cap``:
+    the left end of the final bracket after ``iters`` halvings.
+
+    A K-ary search, K = OGB_TREE_SPLIT: each round evaluates the mass at
+    the K - 1 interior points of the bracket in one batched tree read and
+    keeps the sub-interval where ``mass >= cap`` first fails, so it
+    resolves log2(K) halvings; the last round is narrower when ``iters``
+    is not a multiple of log2(K).  For a monotone mass this is the result
+    of ``iters`` bisection steps in exact arithmetic.  The rounds are
+    unrolled in Python, so the chunk holds no device loop.
+    """
+    rows = _sibling_rows(ycnt, ysum, v, radix)
+    bits = OGB_TREE_SPLIT.bit_length() - 1
+    done = 0
+    while done < iters:
+        r = min(bits, iters - done)
+        k = 1 << r
+        t = lo + (hi - lo) * (jnp.arange(1, k, dtype=jnp.float32) / k)
+        ok = _ogb_tree_mass(rows, total, wv, t, v, radix) >= cap
+        # points[j] is the last point of the leading run of feasible ones
+        # (lo itself when t[0] fails); hi closes the list as infeasible
+        j = jnp.argmin(jnp.concatenate([ok, jnp.zeros(1, bool)]))
+        points = jnp.concatenate([lo[None], t, hi[None]])
+        lo, hi = points[j], points[j + 1]
+        done += r
+    return lo
+
+
 @functools.lru_cache(maxsize=None)
 def make_ogb_tree_chunk(catalog_size: int, v: int, radix: int, sample: str,
                         iters: int = OGB_TREE_ITERS):
@@ -740,7 +829,12 @@ def make_ogb_tree_chunk(catalog_size: int, v: int, radix: int, sample: str,
       ``clip(y - rho, 0, 1)``);
     * the threshold solve uses the bucket mean-clip mass — exact except for
       the <= 2 buckets straddling ``rho`` and ``rho + 1``, so rho carries
-      an O(bucket width) quantization;
+      an O(bucket width) quantization.  It resolves ``iters`` halvings
+      of the warm bracket ``[rho, rho + max(eta*B, 4w)]`` by the K-ary
+      search of :func:`_ogb_tree_solve`: in exact arithmetic the result
+      of ``iters`` bisection steps; in float32 its probe points are
+      rounded differently, so rho may differ from bisection's by about
+      the final bracket width plus an ulp;
     * a touched item is first re-anchored to the clipped f of the dense
       step (``y <- clip(y, rho, 1 + rho)``): one below ``rho`` holds f = 0,
       not a debt.  The upper clip is applied to an item only when it is
@@ -749,25 +843,6 @@ def make_ogb_tree_chunk(catalog_size: int, v: int, radix: int, sample: str,
       differential test bounds the combined drift.
     """
     poisson = sample == "poisson"
-
-    def mass_at(ycnt, ysum, wv, total, t):
-        """sum_b cnt_b * clip(mean_b - t, 0, 1) via O(log V) tree reads."""
-        k0 = _ogb_bucket(t, wv, v)
-        k1 = _ogb_bucket(t + 1.0, wv, v)
-        qc = pt.tree_prefix(ycnt, v, radix, jnp.stack([k0, k1]))
-        qs = pt.tree_prefix(ysum, v, radix, jnp.stack([k0, k1]))
-        cb = jnp.stack([ycnt[k0], ycnt[k1]])
-        sb = jnp.stack([ysum[k0], ysum[k1]])
-        # buckets above k1 are entirely past t+1: full mass
-        above = total - qc[1]
-        # buckets strictly between k0 and k1 lie in the linear clip region
-        mid_c = qc[1] - cb[1] - qc[0]
-        mid_s = qs[1] - sb[1] - qs[0]
-        mid = mid_s - t * mid_c
-        # boundary buckets: mean-clip approximation
-        mean = jnp.where(cb > 0, sb / jnp.maximum(cb, 1.0), 0.0)
-        bnd = cb * jnp.clip(mean - t, 0.0, 1.0)
-        return above + mid + bnd[0] + jnp.where(k1 > k0, bnd[1], 0.0)
 
     def chunk(carry, ids):
         b = ids.shape[0]
@@ -829,22 +904,15 @@ def make_ogb_tree_chunk(catalog_size: int, v: int, radix: int, sample: str,
                                       jnp.concatenate([do, dn]),
                                       jnp.concatenate([-ones, ones]))
 
-        # --- scalar threshold solve: bisect on the warm bracket ---
+        # --- scalar threshold solve: K-ary search on the warm bracket ---
         with jax.named_scope("ogb_tree/solve"):
             total = pt.tree_total(ycnt, v, radix)
             # rho* - rho <= eta*B (chained-projection bound); the 4w floor
             # keeps the bracket wider than the mass quantization when
             # eta*B < w
             hi0 = rho + jnp.maximum(eta * jnp.float32(b), 4.0 * wv)
-
-            def bis(_, lohi):
-                lo, hi = lohi
-                mid = 0.5 * (lo + hi)
-                m = mass_at(ycnt, ysum, wv, total, mid)
-                return (jnp.where(m >= cap, mid, lo),
-                        jnp.where(m >= cap, hi, mid))
-
-            rho_new, _ = jax.lax.fori_loop(0, iters, bis, (rho, hi0))
+            rho_new = _ogb_tree_solve(ycnt, ysum, wv, total, cap, rho, hi0,
+                                      v, radix, iters)
 
         # --- re-anchor when the next chunk could outgrow the value grid ---
         gridtop = wv * jnp.float32(v) - 1.0

@@ -8,9 +8,12 @@ slot automata (same hit sequence, same occupancy); the lazy bucketized
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.cachesim import api
+from repro.cachesim import tree_engines as te
+from repro.kernels.prefix_tree import ops as pt
 from repro.kernels.prefix_tree.ref import stack_distance_hits_ref
 
 AUTOMATA = ["lru", "lfu", "ftpl"]
@@ -139,6 +142,145 @@ def test_ogb_tree_reanchor_path():
 def test_ogb_tree_rejects_madow():
     with pytest.raises(ValueError, match="madow"):
         api.policy_def("ogb_tree", sample="madow")
+
+
+def _mass64(ccum, scum, cnt, sm, w, t):
+    """The bucket mean-clip mass of ``_ogb_tree_mass`` in float64, from
+    exclusive prefix sums ``ccum``/``scum`` of the leaf histograms."""
+    v = cnt.shape[0]
+    k0 = int(np.clip(np.floor((t + 1.0) / w), 0, v - 1))
+    k1 = int(np.clip(np.floor((t + 2.0) / w), 0, v - 1))
+    above = ccum[-1] - ccum[k1 + 1]
+    mid = (scum[k1] - scum[k0 + 1]) - t * (ccum[k1] - ccum[k0 + 1])
+
+    def bnd(k):
+        mean = sm[k] / cnt[k] if cnt[k] > 0 else 0.0
+        return cnt[k] * np.clip(mean - t, 0.0, 1.0)
+
+    return above + mid + bnd(k0) + (bnd(k1) if k1 > k0 else 0.0)
+
+
+@pytest.mark.parametrize("iters", [30, 12, 7])
+@pytest.mark.parametrize("bracket", ["eta_b", "floor_4w", "near_zero",
+                                     "near_top"])
+def test_ogb_tree_solve_matches_float64_bisection(bracket, iters):
+    """The K-ary threshold solve lands where a float64 bisection of the same
+    mass does, within the final bracket width plus float32 rounding, and
+    keeps ``mass(rho) >= cap``."""
+    v, radix = te.OGB_TREE_BUCKETS, te.OGB_TREE_RADIX
+    eta, b = 0.002, 1000
+    span = 1.0 + 2.0 * te.OGB_TREE_GAIN * max(1.0, eta * 4096)
+    w = float(np.float32((span + 1.0) / v))
+    gridtop = w * v - 1.0
+    width = 4.0 * w if bracket == "floor_4w" else eta * b
+    solve = jax.jit(te._ogb_tree_solve, static_argnums=(7, 8, 9))
+    for seed in range(4):
+        rng = np.random.default_rng(seed)
+        rho0 = {"eta_b": rng.uniform(1.0, 8.0),
+                "floor_4w": rng.uniform(1.0, 8.0),
+                "near_zero": 0.0,
+                "near_top": gridtop - 1.0 - width - 2.0 * w}[bracket]
+        rho0 = float(np.float32(rho0))
+        hi0 = float(np.float32(rho0 + width))
+        # items dense around [rho0, hi0 + 1], where the mass moves, plus a
+        # sparse spread over the whole grid
+        y = np.concatenate([
+            rng.uniform(max(rho0 - 1.0, 0.0), hi0 + 1.5, 2000),
+            rng.uniform(0.0, gridtop, 200),
+        ]).astype(np.float32)
+        leaf = np.clip(np.floor((y.astype(np.float64) + 1.0) / w), 0, v - 1)
+        cnt = np.bincount(leaf.astype(np.int64), minlength=v).astype(np.float32)
+        sm = np.zeros(v, np.float32)
+        np.add.at(sm, leaf.astype(np.int64), y)
+        c64, s64 = cnt.astype(np.float64), sm.astype(np.float64)
+        ccum = np.concatenate([[0.0], np.cumsum(c64)])
+        scum = np.concatenate([[0.0], np.cumsum(s64)])
+
+        def mass(t):
+            return _mass64(ccum, scum, c64, s64, w, t)
+
+        # as the gradient step guarantees: mass(rho0) >= cap > mass(hi0)
+        m0, m1 = mass(rho0), mass(hi0)
+        cap = float(np.float32(m1 + rng.uniform(0.05, 0.95) * (m0 - m1)))
+        lo, hi = rho0, hi0
+        for _ in range(iters):
+            mid = 0.5 * (lo + hi)
+            lo, hi = (mid, hi) if mass(mid) >= cap else (lo, mid)
+
+        ycnt = pt.tree_build(jnp.asarray(cnt), radix)
+        ysum = pt.tree_build(jnp.asarray(sm), radix)
+        total = pt.tree_total(ycnt, v, radix)
+        f32 = jnp.float32
+        rho = solve(ycnt, ysum, f32(w), total, f32(cap), f32(rho0), f32(hi0),
+                    v, radix, iters)
+        # the float32 mass carries rounding of about eps32 times its
+        # largest term (measured <= 0.46x on such data), which moves the
+        # root by that over the mass's slope: past ~20 halvings this, not
+        # the bracket, limits agreement with float64
+        err = 4.0 * float(np.finfo(np.float32).eps) * (
+            ccum[-1] + scum[-1] + abs(lo) * ccum[-1])
+        slope = (mass(lo - 1e-3) - mass(lo + 1e-3)) / 2e-3
+        tol = ((hi0 - rho0) / 2**iters + 2.0 * float(np.spacing(rho))
+               + err / slope)
+        assert abs(float(rho) - lo) <= tol, (seed, float(rho), lo, tol)
+        # rho is a left end of the grid that iters halvings of the bracket
+        # cut, up to the float32 rounding of the probe points
+        h = (hi0 - rho0) / 2**iters
+        on_grid = rho0 + round((float(rho) - rho0) / h) * h
+        assert abs(float(rho) - on_grid) <= 8.0 * float(np.spacing(
+            np.float32(hi0)))
+        assert mass(float(rho)) >= cap - err
+
+
+@pytest.mark.parametrize("v,radix", [(65536, 64), (1000, 16), (70, 8),
+                                     (10, 16)])
+def test_ogb_tree_mass_reads_every_tree_geometry(v, radix):
+    """The solve's row reads of the count and sum trees give the float64
+    mass, also where a level's groups are partial (v not a power of the
+    radix) and where the leaves are one group."""
+    rng = np.random.default_rng(v)
+    w = float(np.float32(20.0 / v))
+    cnt = rng.integers(0, 5, v).astype(np.float32)
+    sm = (cnt * rng.uniform(0.0, 18.0, v)).astype(np.float32)
+    ycnt = pt.tree_build(jnp.asarray(cnt), radix)
+    ysum = pt.tree_build(jnp.asarray(sm), radix)
+    t = rng.uniform(-1.0, 19.0, 200).astype(np.float32)
+    got = te._ogb_tree_mass(te._sibling_rows(ycnt, ysum, v, radix),
+                            pt.tree_total(ycnt, v, radix), jnp.float32(w),
+                            jnp.asarray(t), v, radix)
+    c64, s64 = cnt.astype(np.float64), sm.astype(np.float64)
+    ccum = np.concatenate([[0.0], np.cumsum(c64)])
+    scum = np.concatenate([[0.0], np.cumsum(s64)])
+    want = [_mass64(ccum, scum, c64, s64, w, float(x)) for x in t]
+    # float32 rounding: eps32 times the mass's largest terms, as in the
+    # solve test (|t| < 19)
+    eps = float(np.finfo(np.float32).eps)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0,
+                               atol=4.0 * eps * (scum[-1] + 20.0 * ccum[-1]))
+
+
+def _primitive_names(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _primitive_names(inner)
+
+
+def test_ogb_tree_chunk_has_no_while_loop():
+    """The threshold solve's rounds are unrolled: the chunk holds no loop,
+    so the replay's scan is the only ``while`` around it.  (A ``fori_loop``
+    with static bounds traces to ``scan``, which lowers to a ``while``.)"""
+    n, v, radix, b = 64, 256, 16, 16
+    carry = te.init_ogb_tree_carry(n, 8, eta=0.05, buckets=v, radix=radix,
+                                   batch_hint=b)
+    chunk = te.make_ogb_tree_chunk(n, v, radix, "poisson")
+    jaxpr = jax.make_jaxpr(chunk)(carry, jnp.zeros(b, jnp.int32)).jaxpr
+    names = set(_primitive_names(jaxpr))
+    assert "cond" in names  # the walk reaches the re-anchor branch
+    assert not names & {"while", "scan"}
 
 
 def test_madow_tree_sampling_matches_dense_madow():
