@@ -30,12 +30,15 @@ def readings(workload: str, seed: int, control: bool, window_s: float,
              root: Path = ROOT) -> dict:
     import ml_dtypes
 
-    from bench import check, drivers, harness, traffic
+    import jax
 
-    _, cfg, mix = harness.cell_files(harness.load_manifest(root), workload, root)
+    from bench import check, harness, traffic
+
+    cell, cfg, mix = harness.cell_files(harness.load_manifest(root), workload, root)
     ref = harness.load_module(Path(root) / "bench" / "reference" / f"{cfg['reference']}.py",
                               f"bench.reference.{cfg['reference']}")
-    drv = drivers.DRIVERS[mix["mode"]](cfg, mix, seed)
+    devices = jax.devices()[: int(cell["chips"])]
+    drv = harness.load_mode(mix["mode"], root).Driver(cfg, mix, seed, devices)
     drv.setup()
     drv.window(window_s)
     ids, got = drv.checked()

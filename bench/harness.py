@@ -6,13 +6,15 @@ per-layer metric sits in a file of its own, found by the name
 
 * ``configs[].file``: the configuration (a JSON file of sizes, the
   reference's name and the limits of the comparison);
-* ``bench/traffic/<traffic>.json``: the mix, read by ``bench.traffic``
-  and driven by the ``bench.drivers`` class its ``mode`` names;
+* ``bench/traffic/<traffic>.json``: the mix, read by ``bench.traffic``;
+* ``bench/modes/<mode>.py``: the traffic mode the mix names, with
+  ``KEYS``, the exact keys of its mix files, and ``Driver``, built as
+  ``Driver(cfg, mix, seed, devices)`` over the cell's devices;
 * ``bench/metrics/<metric>.py``: a reader, ``read(ctx) -> float | None``;
 * ``bench/reference/<reference>.py``: the plain reference.
 
-Adding a cell, a configuration, a mix or a metric therefore adds files
-and entries, and edits none.
+Adding a cell, a configuration, a mix, a mode, a metric or a reference
+therefore adds files and entries, and edits none.
 """
 
 from __future__ import annotations
@@ -20,12 +22,16 @@ from __future__ import annotations
 import gc
 import importlib.util
 import json
+import re
 import shutil
 import sys
 import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
+
+#: what a mode's name may be: a file name under ``bench/modes/``
+MODE_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 
 #: longest traced window: a trace holds an event per device op, and a
 #: window of dense replay runs some hundred thousand ops a second
@@ -44,14 +50,24 @@ def find(entries: list, name: str, what: str) -> dict:
 
 
 def load_module(path: Path, name: str):
-    """Import a reader or a reference from its file, whose name may hold
-    dots (``api_host_ms_per_call.serve.py``)."""
+    """Import a reader, a reference or a mode from its file, whose name may
+    hold dots (``api_host_ms_per_call.serve.py``)."""
     spec = importlib.util.spec_from_file_location(name, path)
     if spec is None or not Path(path).is_file():
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_mode(mode: str, root: Path = ROOT):
+    """The module of a traffic mode: ``bench/modes/<mode>.py``."""
+    modes = Path(root) / "bench" / "modes"
+    path = modes / f"{mode}.py"
+    if not isinstance(mode, str) or not MODE_NAME.fullmatch(mode) or not path.is_file():
+        found = sorted(str(p) for p in modes.glob("*.py"))
+        raise ValueError(f"no traffic mode {mode!r}: looked for {path} among {found}")
+    return load_module(path, f"bench.modes.{mode}")
 
 
 def cell_files(manifest: dict, workload: str, root: Path = ROOT) -> tuple:
@@ -61,7 +77,7 @@ def cell_files(manifest: dict, workload: str, root: Path = ROOT) -> tuple:
     cell = find(manifest["workloads"], workload, "workload")
     entry = find(manifest["configs"], cell["config"], "config")
     cfg = json.loads((Path(root) / entry["file"]).read_text())
-    mix = traffic.load_mix(Path(root) / "bench" / "traffic" / f"{cell['traffic']}.json")
+    mix = traffic.load_mix(Path(root) / "bench" / "traffic" / f"{cell['traffic']}.json", root)
     return cell, cfg, mix
 
 
@@ -88,13 +104,13 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
     tests call this on the CPU at tiny sizes."""
     import jax
 
-    from bench import check, drivers, traffic
+    from bench import check, traffic
     from repro.analysis.recompile import track_compiles
 
     manifest = load_manifest(root)
     cell, cfg, mix = cell_files(manifest, workload, root)
     devices = jax.devices()[: int(cell["chips"])]
-    driver = drivers.DRIVERS[mix["mode"]](cfg, mix, seed)
+    driver = load_mode(mix["mode"], root).Driver(cfg, mix, seed, devices)
     driver.setup()
     window_s = min(seconds, TRACE_SECONDS) if trace else seconds
     setup_s = time.perf_counter() - t_start

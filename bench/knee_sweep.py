@@ -27,12 +27,15 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
 def sweep(workload: str, seed: int, seconds: float, rates, root: Path = ROOT):
-    from bench import drivers, harness
+    import jax
 
-    _, cfg, mix = harness.cell_files(harness.load_manifest(root), workload, root)
+    from bench import harness
+
+    cell, cfg, mix = harness.cell_files(harness.load_manifest(root), workload, root)
     if mix["mode"] != "serve":
         raise ValueError(f"{workload} is not an open-loop serving cell")
-    drv = drivers.DRIVERS["serve"](cfg, mix, seed)
+    devices = jax.devices()[: int(cell["chips"])]
+    drv = harness.load_mode("serve", root).Driver(cfg, mix, seed, devices)
     drv.setup()
     for rate in rates:
         drv.rate = float(rate)

@@ -18,25 +18,17 @@ from pathlib import Path
 
 import numpy as np
 
-#: the keys every mix file gives, by mode; a mix may not add others
-MODE_KEYS = {
-    "replay": {"mode", "alpha", "ring_segments", "setup_segments"},
-    "stream": {"mode", "alpha", "ring_segments", "setup_segments", "chunk",
-               "prefetch", "opt_window"},
-    "serve": {"mode", "alpha", "ring_decisions", "setup_decisions",
-              "rate_per_s", "drain_s"},
-}
+from bench.harness import ROOT, load_mode
 
 
-def load_mix(path: Path) -> dict:
-    """A mix file, checked against the keys its mode needs."""
+def load_mix(path: Path, root: Path = ROOT) -> dict:
+    """A mix file, checked against the keys of the mode it names
+    (``bench/modes/<mode>.py`` under ``root``)."""
     mix = json.loads(Path(path).read_text())
-    mode = mix.get("mode")
-    if mode not in MODE_KEYS:
-        raise ValueError(f"{path}: mode {mode!r} is not one of {sorted(MODE_KEYS)}")
-    if set(mix) != MODE_KEYS[mode]:
+    keys = load_mode(mix.get("mode"), root).KEYS
+    if set(mix) != keys:
         raise ValueError(
-            f"{path}: a {mode} mix has exactly the keys {sorted(MODE_KEYS[mode])}, "
+            f"{path}: a {mix['mode']} mix has exactly the keys {sorted(keys)}, "
             f"got {sorted(mix)}"
         )
     return mix

@@ -4,7 +4,9 @@
 directory and cuts every configuration's catalog and capacity by 100 and
 its segment to 64 windows, so each cell runs end to end in a few seconds.
 The roofline metric is left out there: the CPU has no entry in the table
-of peaks, and an unknown device is an error by design.
+of peaks, and an unknown device is an error by design.  Two cells that
+the manifest does not hold are added there as files: open-loop serving,
+and a fleet of four dense OGB tenants checked by a test-local reference.
 """
 
 from __future__ import annotations
@@ -43,6 +45,31 @@ def add_serve_cell(root, man):
                                  "moves": "decision_p95_ms", "workloads": [SERVE]})
 
 
+#: A fleet of four dense OGB tenants (mode ``fleet``), each a tenth of
+#: ``cdn_ogb_1e6`` cut by ten again, checked tenant by tenant against
+#: ``bench/reference/ogb.py`` through ``fleet_reference.py``.
+FLEET_MIX = {"mode": "fleet", "alpha": 0.9, "ring_segments": 3, "setup_segments": 2}
+FLEET = "tiny_fleet.fleet"
+
+
+def add_fleet_cell(root, man):
+    cfg = json.loads((REPO / "bench" / "configs" / "cdn_ogb_1e6.json").read_text())
+    cfg.update(name="tiny_fleet", reference="ogb_fleet", tenants=4, catalog_size=10_000,
+               capacity=500, segment=8 * cfg["window"])
+    (root / "bench" / "configs" / "tiny_fleet.json").write_text(json.dumps(cfg))
+    (root / "bench" / "traffic" / "fleet.json").write_text(json.dumps(FLEET_MIX))
+    shutil.copy(Path(__file__).with_name("fleet_reference.py"),
+                root / "bench" / "reference" / "ogb_fleet.py")
+    man["configs"].append({"name": "tiny_fleet", "source": "test", "reduced": [],
+                           "why": "test", "file": "bench/configs/tiny_fleet.json"})
+    man["workloads"].append({"name": FLEET, "config": "tiny_fleet", "traffic": "fleet",
+                             "chips": 1, "why": "test"})
+    for m in man["end_to_end"] + man["per_layer"]:
+        if m["name"] in ("requests_per_s", "device_idle_share.replay", "device_us_per_window",
+                         "api_host_ms_per_call.replay"):
+            m["workloads"].append(FLEET)
+
+
 @pytest.fixture
 def tiny_root(tmp_path):
     root = tmp_path / "checkout"
@@ -57,5 +84,6 @@ def tiny_root(tmp_path):
         path.write_text(json.dumps(cfg))
     man["per_layer"] = [m for m in man["per_layer"] if m["name"] != "dense_step_roofline"]
     add_serve_cell(root, man)
+    add_fleet_cell(root, man)
     (root / "BENCHMARK.json").write_text(json.dumps(man))
     return root

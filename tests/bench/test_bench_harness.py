@@ -3,8 +3,9 @@
 Every cell runs through ``bench.harness.execute`` (the whole of a run but
 the look for a chip), with the profiler off and on; the entry script
 refuses to run without an accelerator or without the program; a new
-configuration, mix and metric are found by name as new files; and a run
-whose timed path is broken underneath comes out not correct.
+configuration, mix, metric, mode and reference are found by name as new
+files; and a run whose timed path is broken underneath comes out not
+correct.
 """
 
 from __future__ import annotations
@@ -26,7 +27,8 @@ import pytest
 from bench import drivers, harness
 
 REPO = Path(__file__).resolve().parents[2]
-CELLS = ["cdn_ogb_tree_1e6.zipf", "cdn_ogb_1e6.zipf", "cdn_ogb_1e6.serve", "cdn_ogb_1e6.stream"]
+CELLS = ["cdn_ogb_tree_1e6.zipf", "cdn_ogb_1e6.zipf", "cdn_ogb_1e6.serve", "cdn_ogb_1e6.stream",
+         "tiny_fleet.fleet"]
 
 
 def _execute(root, workload, trace=False, seconds=0.3, seed=2**31 + 17):
@@ -135,6 +137,118 @@ def test_new_config_mix_and_metric_are_found_by_name(tiny_root):
                                         "metrics/windows_replayed.py"}
 
 
+#: A mode that enters as a file: segments drawn afresh from the traffic
+#: generator for every call, set-up and window alike.
+FRESH_MODE = '''
+import time
+
+from bench import drivers, traffic
+
+KEYS = {"mode", "alpha", "setup_segments"}
+
+
+class Driver(drivers.Driver):
+    def _draw(self):
+        return traffic.zipf_ids(self.cdf, int(self.cfg["segment"]), self.rng)
+
+    def setup(self):
+        for _ in range(int(self.mix["setup_segments"])):
+            ids = self._draw()
+            self._keep(ids, self._call(ids))
+
+    def window(self, seconds):
+        requests, t0 = 0, time.perf_counter()
+        while time.perf_counter() < t0 + seconds:
+            requests += int(self._call(self._draw()).T)
+        dt = time.perf_counter() - t0
+        return drivers.WindowStats(dt, requests, requests // self.b, requests, 0)
+'''
+
+#: A plain LRU that enters as a file: per window, the requests in order
+#: through an ordered dict; the reward is the hits, the occupancy is
+#: counted after the window.
+LRU_REFERENCE = '''
+from collections import OrderedDict
+
+import numpy as np
+
+
+def replay(windows, cfg, policy_seed, dtype=np.float64):
+    cache, c = OrderedDict(), int(cfg["capacity"])
+    out = {k: np.zeros(len(windows)) for k in ("reward", "hits", "occupancy")}
+    for w, ids in enumerate(windows):
+        for j in ids.tolist():
+            if j in cache:
+                cache.move_to_end(j)
+                out["hits"][w] += 1
+            else:
+                if len(cache) >= c:
+                    cache.popitem(last=False)
+                cache[j] = None
+        out["reward"][w] = out["hits"][w]
+        out["occupancy"][w] = len(cache)
+    return out
+'''
+
+
+def test_new_mode_and_a_policy_with_no_learning_rate_are_found_by_name(tiny_root):
+    before = _digest(tiny_root / "bench")
+    cfg = json.loads((tiny_root / "bench/configs/cdn_ogb_1e6.json").read_text())
+    cfg.update(name="small_lru", policy="lru", reference="lru", catalog_size=5000,
+               capacity=250, segment=4 * cfg["window"],
+               limits={"reward_gap": 0, "hit_count_gap": 0, "occupancy_gap": 0,
+                       "window_compiles": 0})
+    (tiny_root / "bench/configs/small_lru.json").write_text(json.dumps(cfg))
+    (tiny_root / "bench/traffic/fresh.json").write_text(json.dumps(
+        {"mode": "fresh", "alpha": 0.8, "setup_segments": 3}))
+    (tiny_root / "bench/modes/fresh.py").write_text(FRESH_MODE)
+    (tiny_root / "bench/reference/lru.py").write_text(LRU_REFERENCE)
+    man = json.loads((tiny_root / "BENCHMARK.json").read_text())
+    man["configs"].append({"name": "small_lru", "source": "test", "reduced": [], "why": "test",
+                           "file": "bench/configs/small_lru.json"})
+    man["workloads"].append({"name": "small_lru.fresh", "config": "small_lru",
+                             "traffic": "fresh", "chips": 1, "why": "test"})
+    next(m for m in man["end_to_end"] if m["name"] == "requests_per_s")["workloads"].append(
+        "small_lru.fresh")
+    (tiny_root / "BENCHMARK.json").write_text(json.dumps(man))
+    res, _, _ = _execute(tiny_root, "small_lru.fresh")
+    assert res["correct"] is True, res["checks"]
+    assert all(c["value"] == 0 for c in res["checks"].values())
+    assert res["metrics"]["requests_per_s"]["value"] > 0
+    after = _digest(tiny_root / "bench")
+    assert {k: after[k] for k in before} == before
+    assert set(after) - set(before) == {"configs/small_lru.json", "traffic/fresh.json",
+                                        "modes/fresh.py", "reference/lru.py"}
+
+
+def test_an_unknown_mode_names_the_files_looked_for(tiny_root):
+    (tiny_root / "bench/traffic/odd.json").write_text(json.dumps({"mode": "odd", "alpha": 1}))
+    with pytest.raises(ValueError, match=r"modes/odd\.py.*modes/replay\.py"):
+        harness.cell_files({"workloads": [{"name": "w", "config": "c", "traffic": "odd"}],
+                            "configs": [{"name": "c", "file": "bench/configs/cdn_ogb_1e6.json"}]},
+                           "w", tiny_root)
+
+
+def test_fleet_checks_the_listed_tenants_or_the_ends_of_each_device_slice(tiny_root):
+    fleet = harness.load_mode("fleet", tiny_root)
+    assert fleet.default_checked(2048, 4) == [0, 511, 512, 1023, 1024, 1535, 1536, 2047]
+    assert fleet.default_checked(4, 1) == [0, 3]
+    assert fleet.default_checked(1, 1) == [0]
+    assert fleet.default_checked(2, 4) == [0, 1]
+    _, cfg, mix = harness.cell_files(harness.load_manifest(tiny_root), "tiny_fleet.fleet",
+                                     tiny_root)
+    import jax
+
+    drv = fleet.Driver({**cfg, "checked_tenants": [2, 1]}, mix, 11, jax.devices()[:1])
+    assert drv.check == [2, 1] and drv.seeds[3] == (drv.policy_seed + 3) % 2**31
+    assert drv.ring.shape == (mix["ring_segments"], cfg["tenants"], cfg["segment"])
+    again = fleet.Driver(cfg, mix, 11, jax.devices()[:1])
+    assert again.check == [0, 3] and np.array_equal(again.ring, drv.ring)
+    assert not np.array_equal(drv.ring[0, 0], drv.ring[0, 1])
+    with pytest.raises(ValueError, match="checked_tenants"):
+        fleet.Driver({**cfg, "checked_tenants": [4]}, mix, 11, jax.devices()[:1])
+
+
 def _fault(name):
     """A step broken underneath the timed path, the way a wrong change
     would break it."""
@@ -170,16 +284,23 @@ def test_a_broken_timed_path_is_not_correct(tiny_root, monkeypatch, workload, fa
 
 
 def test_a_compile_inside_the_window_is_not_correct(tiny_root, monkeypatch):
-    real = drivers.Replay.window
+    real = harness.load_mode
 
-    def window(self, seconds):
-        import jax
-        import jax.numpy as jnp
+    def load_mode(mode, root=harness.ROOT):
+        mod = real(mode, root)
+        timed = mod.Driver.window
 
-        jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
-        return real(self, seconds)
+        def window(self, seconds):
+            import jax
+            import jax.numpy as jnp
 
-    monkeypatch.setattr(drivers.Replay, "window", window)
+            jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+            return timed(self, seconds)
+
+        mod.Driver.window = window
+        return mod
+
+    monkeypatch.setattr(harness, "load_mode", load_mode)
     res, _, _ = _execute(tiny_root, "cdn_ogb_1e6.zipf")
     assert res["correct"] is False
     assert res["checks"]["window_compiles"]["value"] >= 1
