@@ -36,6 +36,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import AxisType
 
 from repro.core.regret import best_static_hits
@@ -297,74 +298,107 @@ def run_fleet(
     (donated — hand it off, don't keep references).  With a live mesh
     (``mesh=`` or an ambient ``dist.sharding.use_sharding``), the tenant
     axis shards over the mesh's data axis.
+
+    **Tracing:** under ``jax.profiler.trace`` the call shows as one
+    ``repro.fleet`` span (arguments ``tenants``, ``windows`` per tenant and
+    ``bytes_in``) tiled by ``repro.fleet.upload`` (the host stack of the
+    ids and, on a mesh, their ``device_put`` onto the tenant sharding: two
+    spans, around ``init`` on a fresh call)/``init``/``dispatch``
+    (``repro.run.compile`` nested on a cache miss)/``wait``/``readback``/
+    ``opt``.
     """
     if not pd.trace_driven:
         raise ValueError(
             f"policy kind {pd.kind!r} is not trace-driven; the fleet "
             "replays per-tenant request streams"
         )
-    chunks, used, t_used = _tenant_chunks(traces, window)
-    n_tenants = chunks.shape[0]
-    sharding = _tenant_sharding(n_tenants, mesh, rules)
+    # the span's arguments, read off the traces before the stack it times
+    n_rows = len(traces)
+    m = np.size(traces[0]) // window if n_rows else 0
+    with TraceAnnotation(
+        "repro.fleet", tenants=n_rows, windows=m, bytes_in=4 * n_rows * m * window
+    ):
+        with TraceAnnotation("repro.fleet.upload"):
+            chunks, used, t_used = _tenant_chunks(traces, window)
+        n_tenants = chunks.shape[0]
+        sharding = _tenant_sharding(n_tenants, mesh, rules)
 
-    if carry is None:
-        if catalog_size is None or capacities is None:
-            raise ValueError(
-                "run_fleet() needs catalog_size and capacities (or carry=)"
+        if carry is None:
+            if catalog_size is None or capacities is None:
+                raise ValueError(
+                    "run_fleet() needs catalog_size and capacities (or carry=)"
+                )
+            caps = _tenant_array(capacities, n_tenants, "capacities")
+            seed_arr = _tenant_array(
+                seeds if seeds is not None else np.arange(n_tenants),
+                n_tenants,
+                "seeds",
             )
-        caps = _tenant_array(capacities, n_tenants, "capacities")
-        seed_arr = _tenant_array(
-            seeds if seeds is not None else np.arange(n_tenants),
-            n_tenants,
-            "seeds",
-        )
-        hor = _tenant_array(
-            horizons if horizons is not None else t_used, n_tenants, "horizons"
-        )
-        eta_list = _tenant_etas(etas, n_tenants)
-        slots = int(n_slots) if n_slots is not None else int(caps.max())
-        stacked, etas_out = _build_fleet_carries(
-            pd, catalog_size, caps, seed_arr, eta_list, hor, window, slots,
-            sizes, costs, init_kw, sharding,
-        )
-    else:
-        _reject_resume_kwargs(seeds, etas, horizons, n_slots, costs, init_kw)
-        stacked = carry
-        lead = {int(np.shape(x)[0]) for x in jax.tree.leaves(carry)}
-        if lead != {n_tenants}:
-            raise ValueError(
-                f"carry tenant axis {sorted(lead)} does not match "
-                f"{n_tenants} tenant traces"
+            hor = _tenant_array(
+                horizons if horizons is not None else t_used,
+                n_tenants,
+                "horizons",
             )
-        caps = (
-            _tenant_array(capacities, n_tenants, "capacities")
-            if capacities is not None
-            else np.full(n_tenants, -1)
-        )
-        seed_arr = np.full(n_tenants, -1)
-        etas_out = None
+            eta_list = _tenant_etas(etas, n_tenants)
+            slots = int(n_slots) if n_slots is not None else int(caps.max())
+            with TraceAnnotation("repro.fleet.init"):
+                stacked, etas_out = _build_fleet_carries(
+                    pd, catalog_size, caps, seed_arr, eta_list, hor, window,
+                    slots, sizes, costs, init_kw, sharding,
+                )
+        else:
+            _reject_resume_kwargs(seeds, etas, horizons, n_slots, costs, init_kw)
+            stacked = carry
+            lead = {int(np.shape(x)[0]) for x in jax.tree.leaves(carry)}
+            if lead != {n_tenants}:
+                raise ValueError(
+                    f"carry tenant axis {sorted(lead)} does not match "
+                    f"{n_tenants} tenant traces"
+                )
+            caps = (
+                _tenant_array(capacities, n_tenants, "capacities")
+                if capacities is not None
+                else np.full(n_tenants, -1)
+            )
+            seed_arr = np.full(n_tenants, -1)
+            etas_out = None
 
-    jitted = api._fleet_jit(pd.step)
-    if sharding is not None:
-        stacked, chunks = jax.device_put((stacked, chunks), sharding)
-    t0 = time.perf_counter()
-    final, out = api._compiled(jitted, stacked, chunks)(stacked, chunks)
-    jax.block_until_ready(out)
-    wall = time.perf_counter() - t0
+        jitted = api._fleet_jit(pd.step)
+        if sharding is not None:
+            with TraceAnnotation("repro.fleet.upload"):
+                stacked, chunks = jax.device_put((stacked, chunks), sharding)
+        t0 = time.perf_counter()
+        with TraceAnnotation("repro.fleet.dispatch"):
+            final, out = api._compiled(jitted, stacked, chunks)(stacked, chunks)
+        with TraceAnnotation("repro.fleet.wait"):
+            jax.block_until_ready(out)
+        wall = time.perf_counter() - t0
 
-    if track_opt and caps.min() >= 0:
-        opt = np.array(
-            [
-                float(best_static_hits(used[e], int(caps[e])))
-                for e in range(n_tenants)
-            ]
-        )
-    else:
-        opt = np.zeros(n_tenants)
+        if track_opt and caps.min() >= 0:
+            with TraceAnnotation("repro.fleet.opt"):
+                opt = np.array(
+                    [
+                        float(best_static_hits(used[e], int(caps[e])))
+                        for e in range(n_tenants)
+                    ]
+                )
+        else:
+            opt = np.zeros(n_tenants)
 
-    bytes_total = None
-    if sizes is not None:
-        bytes_total = np.asarray(sizes, np.float64)[used].sum(axis=1)
+        bytes_total = None
+        if sizes is not None:
+            bytes_total = np.asarray(sizes, np.float64)[used].sum(axis=1)
+
+        with TraceAnnotation("repro.fleet.readback"):
+            reward = np.asarray(out.reward, np.float64)
+            hits = np.asarray(out.hits, np.int64)
+            aux = np.asarray(out.aux, np.float64)
+            occupancy = np.asarray(out.occupancy, np.float64)
+            byte_hits = (
+                np.asarray(out.byte_hits, np.float64)
+                if out.byte_hits is not None
+                else None
+            )
 
     return FleetResult(
         name=name or pd.name,
@@ -375,18 +409,14 @@ def run_fleet(
         capacities=caps,
         seeds=seed_arr,
         etas=etas_out,
-        reward=np.asarray(out.reward, np.float64),
-        hits=np.asarray(out.hits, np.int64),
-        aux=np.asarray(out.aux, np.float64),
-        occupancy=np.asarray(out.occupancy, np.float64),
+        reward=reward,
+        hits=hits,
+        aux=aux,
+        occupancy=occupancy,
         opt_hits=opt,
         carry=final if keep_carry else None,
         wall_seconds=wall,
-        byte_hits=(
-            np.asarray(out.byte_hits, np.float64)
-            if out.byte_hits is not None
-            else None
-        ),
+        byte_hits=byte_hits,
         bytes_total=bytes_total,
     )
 

@@ -1,9 +1,10 @@
 """The program's own spans and named scopes, read back from CPU traces.
 
-``api.run`` and ``run_stream`` write ``jax.profiler.TraceAnnotation``
-spans under ``repro.*`` names; the OGB engines trace their phases under
-``jax.named_scope`` paths (``ogb_tree/<phase>``, ``ogb/<phase>``) that
-reach the executables' HLO ``op_name`` metadata.  Each test records a
+``api.run``, ``run_fleet`` and ``run_stream`` write
+``jax.profiler.TraceAnnotation`` spans under ``repro.*`` names; the OGB
+engines trace their phases under ``jax.named_scope`` paths
+(``ogb_tree/<phase>``, ``ogb/<phase>``) that reach the executables' HLO
+``op_name`` metadata.  Each test records a
 trace on the CPU and checks the spans it holds, and how they nest.
 """
 
@@ -11,11 +12,14 @@ from __future__ import annotations
 
 import re
 
+import numpy as np
 import pytest
 
 import jax
+from jax.sharding import Mesh
 
 from repro.cachesim import api
+from repro.cachesim.fleet import run_fleet
 from repro.cachesim.tracelab import run_stream
 from repro.cachesim.traces import zipf
 
@@ -23,6 +27,8 @@ N, C, WINDOW = 400, 20, 50
 RUN_CHILDREN = ("repro.run.upload", "repro.run.init", "repro.run.lookup",
                 "repro.run.dispatch", "repro.run.wait", "repro.run.readback",
                 "repro.run.opt")
+FLEET_CHILDREN = ("repro.fleet.upload", "repro.fleet.init", "repro.fleet.dispatch",
+                  "repro.fleet.wait", "repro.fleet.readback", "repro.fleet.opt")
 
 
 def _spans(trace_dir):
@@ -77,6 +83,36 @@ def test_run_spans_tile_the_call(tmp_path):
         pd, trace, capacity=C, carry=first[0].carry, window=WINDOW, track_opt=False))
     assert {s[0] for s in resumed} == {"repro.run"} | (
         set(RUN_CHILDREN) - {"repro.run.init", "repro.run.opt"})
+
+
+def test_fleet_spans_tile_the_call(tmp_path):
+    pd = api.policy_def("ogb")
+    tenants, windows = 3, 6
+    traces = np.stack([zipf(N, windows * WINDOW + 7, alpha=0.9, seed=10 + e)
+                       for e in range(tenants)])
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    api.clear_executable_cache()
+    first = []
+    fresh = _traced(tmp_path, lambda: first.append(
+        run_fleet(pd, traces, N, C, window=WINDOW, mesh=mesh)))
+    resumed = _traced(tmp_path / "resumed", lambda: run_fleet(
+        pd, traces, window=WINDOW, carry=first[0].carry, track_opt=False, mesh=mesh))
+    for spans in (fresh, resumed):
+        (top,) = [s for s in spans if s[0] == "repro.fleet"]
+        assert top[4] == {"tenants": tenants, "windows": windows,
+                          "bytes_in": 4 * tenants * windows * WINDOW}
+        for s in spans:
+            if s[0] in FLEET_CHILDREN:
+                assert _parent(spans, s) == "repro.fleet", s[0]
+        # the host stack of the ids, then their copy onto the tenant sharding
+        assert [s[0] for s in spans].count("repro.fleet.upload") == 2
+    assert {s[0] for s in fresh} == {"repro.fleet", "repro.run.compile", *FLEET_CHILDREN}
+    (compile_,) = [s for s in fresh if s[0] == "repro.run.compile"]
+    assert _parent(fresh, compile_) == "repro.fleet.dispatch"
+    # a resumed call on a compiled shape neither initializes nor compiles,
+    # and tracks no OPT when told not to
+    assert {s[0] for s in resumed} == {"repro.fleet"} | (
+        set(FLEET_CHILDREN) - {"repro.fleet.init", "repro.fleet.opt"})
 
 
 def test_run_without_blocking_neither_waits_nor_reads_back(tmp_path):
