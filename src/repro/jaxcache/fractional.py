@@ -78,6 +78,36 @@ def capped_simplex_project(
     return jnp.clip(y - tau, 0.0, 1.0), tau
 
 
+_TILE = 1024  # float32 elements in one (8, 128) TPU tile
+_LANES = 128
+_MAX_ROWS = 1024  # most terms in one partial sum of a sweep
+
+
+def _sweep_layout(y: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Split a 1-D y into (G, R, 128) blocks of whole tiles and a < 1024 tail.
+
+    R is the largest multiple of 8 up to _MAX_ROWS that divides the blocks'
+    rows, so the split depends on len(y) alone; G = 0 when len(y) < 1024."""
+    tiles = y.shape[0] // _TILE
+    rows = 8 * max(k for k in range(1, _MAX_ROWS // 8 + 1) if tiles % k == 0)
+    main = tiles * _TILE
+    return y[:main].reshape(-1, rows, _LANES), y[main:]
+
+
+def _mass_count(z: jax.Array, t: jax.Array, axis: int):
+    """(mass, interior count) of clip(z - t, 0, 1), reduced over ``axis``.
+
+    One variadic reduce yields both, so a sweep reads z once."""
+    clipped = jnp.clip(z - t, 0.0, 1.0)
+    interior = jnp.logical_and(clipped > 0.0, clipped < 1.0).astype(jnp.float32)
+    return jax.lax.reduce(
+        (clipped, interior),
+        (jnp.float32(0.0), jnp.float32(0.0)),
+        lambda a, b: (a[0] + b[0], a[1] + b[1]),
+        (axis,),
+    )
+
+
 def capped_simplex_project_warm(
     y: jax.Array,
     capacity: float,
@@ -106,34 +136,22 @@ def capped_simplex_project_warm(
     hi = jnp.asarray(hi, jnp.float32)
     t = jnp.clip(jnp.asarray(tau0, jnp.float32), lo, hi)
 
-    # y is fixed across sweeps: pad once to a block multiple so each sweep is
-    # a single blocked traversal.  -inf pads contribute 0 mass / 0 count for
-    # any threshold.
-    block = 64
-    yr = y.ravel()
-    pad = (-yr.shape[0]) % block
-    if pad:
-        yr = jnp.pad(yr, (0, pad), constant_values=-jnp.inf)
-    yb = yr.reshape(-1, block)
+    # Each sweep reads y lane-dense.  On the TPU a 1-D float32 array lies in
+    # tiles of 1024 elements, one (8, 128) vreg each, so the whole tiles of y
+    # view as (G, R, 128) blocks with every lane holding a catalog item: a
+    # sweep reads each element once, at 4 bytes, and reducing over R adds
+    # across vregs on the VPU.  The (G, 128) partials hold at most _MAX_ROWS
+    # terms each before the pairwise jnp.sum, which bounds the float32 error
+    # on every backend.  The blocks do not change between sweeps, so XLA
+    # slices them out of y once, before the loop.
+    blocks, tail = _sweep_layout(y.ravel())
 
     def body(_, carry):
         lo, hi, t = carry
-        # one catalog traversal: a variadic per-block reduce yields mass and
-        # interior count together (two separate jnp.sums cost ~5x more on
-        # CPU), and the pairwise jnp.sum over block partials keeps the
-        # accumulation error at pairwise-summation level
-        clipped = jnp.clip(yb - t, 0.0, 1.0)
-        interior = jnp.logical_and(clipped > 0.0, clipped < 1.0).astype(
-            jnp.float32
-        )
-        pm, pc = jax.lax.reduce(
-            (clipped, interior),
-            (jnp.float32(0.0), jnp.float32(0.0)),
-            lambda a, b: (a[0] + b[0], a[1] + b[1]),
-            (1,),
-        )
-        mass = jnp.sum(pm)
-        cnt = jnp.sum(pc)
+        pm, pc = _mass_count(blocks, t, axis=1)
+        tm, tc = _mass_count(tail, t, axis=0)
+        mass = jnp.sum(pm) + tm
+        cnt = jnp.sum(pc) + tc
         too_much = mass >= cap
         lo = jnp.where(too_much, t, lo)
         hi = jnp.where(too_much, hi, t)

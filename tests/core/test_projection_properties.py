@@ -8,10 +8,14 @@ cold bisection and with the float64 oracle — the warm-vs-cold contract every
 device path (scan replay, sharded, Pallas) relies on.
 """
 
+import re
+
 import numpy as np
+import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.projection import (
@@ -137,6 +141,78 @@ def test_warm_vs_cold_tau_agreement(n, c_frac, eta, batch, seed):
         assert abs(float(tau_warm) - tau_oracle) < 1e-4
     bis = capped_simplex_tau_bisect(y64, C, iters=80)
     assert abs(np.clip(y64 - bis, 0.0, 1.0).sum() - C) < 1e-9
+
+
+def _zipf_step(n, seed, batch=1000, eta=0.05):
+    """A float32 OGB step at catalog size n: feasible f plus a zipf batch."""
+    rng = np.random.default_rng(seed)
+    C = max(1, n // 20)
+    f = project_capped_simplex(rng.random(n) * 2 * C / n, C).astype(np.float32)
+    ids = np.minimum(rng.zipf(1.3, size=batch) - 1, n - 1)
+    y = np.asarray(jnp.asarray(f).at[jnp.asarray(ids)].add(np.float32(eta)))
+    return y, C, warm_bracket_hi(eta * batch)
+
+
+# n < 128 and n = 1000 are all tail; 1_000_003 is no multiple of 64 or 128
+# and leaves a tail beside (G, R, 128) blocks; 1e6 is the benchmark's catalog
+@pytest.mark.parametrize("n", [100, 1000, 1_000_003, 1_000_000])
+@pytest.mark.parametrize("tenants", [1, 3])
+def test_warm_sweep_layout_matches_oracle(n, tenants):
+    """The blocked sweep's tau and f == the float64 oracle, alone and vmapped.
+
+    ``tenants`` > 1 runs the projection under ``jax.vmap`` over stacked
+    instances, as the fleet does.  sweeps=25 runs the solver to float32
+    convergence from tau0 = 0, so what is left is the sweep's summation."""
+    steps = [_zipf_step(n, seed) for seed in range(tenants)]
+    y = jnp.asarray(np.stack([s[0] for s in steps]))
+    C = steps[0][1]
+    hi = jnp.stack([s[2] for s in steps])
+    project = lambda yy, h: capped_simplex_project_warm(
+        yy, float(C), jnp.float32(0.0), h, jnp.float32(0.0), sweeps=25
+    )
+    if tenants == 1:
+        f, tau = jax.jit(project)(y[0], hi[0])
+        f, tau = f[None], tau[None]
+    else:
+        f, tau = jax.jit(jax.vmap(project))(y, hi)
+    for e, (y_e, _, _) in enumerate(steps):
+        y64 = y_e.astype(np.float64)
+        tau_ref = capped_simplex_tau(y64, C)
+        assert abs(float(tau[e]) - tau_ref) < 2e-5, (e, float(tau[e]), tau_ref)
+        np.testing.assert_allclose(
+            np.asarray(f[e]), np.clip(y64 - tau_ref, 0.0, 1.0), atol=2e-6
+        )
+
+
+@pytest.mark.parametrize("tenants", [1, 3])
+def test_warm_sweep_reduces_lane_dense_blocks(tenants):
+    """The lowered sweep reduces a (.., 128k)-minor operand, never 64-wide.
+
+    A 64-wide minor dimension fills half of each 128-lane TPU tile, so each
+    sweep would read twice the bytes of y and reduce across lanes."""
+    n = 1_000_003
+    y = jax.ShapeDtypeStruct((tenants, n) if tenants > 1 else (n,), jnp.float32)
+    hi = jax.ShapeDtypeStruct((tenants,) if tenants > 1 else (), jnp.float32)
+    project = lambda yy, h: capped_simplex_project_warm(
+        yy, 500.0, jnp.float32(0.0), h, jnp.float32(0.0)
+    )
+    if tenants > 1:
+        project = jax.vmap(project)
+    text = jax.jit(project).lower(y, hi).as_text()
+    # the variadic (mass, count) reduces: their axis and operand shape
+    variadic = [
+        (int(axis), [int(d) for d in dims.split("x")])
+        for axis, dims in re.findall(
+            r"stablehlo\.reduce\([^)]*\), \([^)]*\) across dimensions = "
+            r"\[(\d+)\] : \(tensor<([\dx]+)xf32>",
+            text,
+        )
+    ]
+    assert any(
+        len(shape) >= 2 and shape[-1] % 128 == 0 and axis != len(shape) - 1
+        for axis, shape in variadic
+    ), variadic
+    assert not re.search(r"tensor<[\dx]*x64xf32>", text)
 
 
 def test_stub_or_real_hypothesis_importable():
