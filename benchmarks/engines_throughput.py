@@ -11,7 +11,7 @@ gives the baseline of the speedup column.
 
 Writes ``benchmarks/results/engines_throughput.json`` and the tracked
 top-level ``BENCH_engines.json`` so the perf trajectory is visible PR over
-PR (same pattern as ``BENCH_throughput.json``).
+PR.
 
 Also exercises the unified sweep layer: one (capacities x seeds) LRU grid
 must cost close to a single replay, not |grid| replays.

@@ -2,7 +2,7 @@
 
 Claim reproduced: recency/frequency policies collapse (linear regret) while
 gradient policies track OPT = C/N.  Every policy now runs device-resident
-(:mod:`repro.cachesim.engines` / :mod:`repro.cachesim.replay`) through the
+(:mod:`repro.cachesim.engines`, through :mod:`repro.cachesim.api`) via the
 ``fig2_adversarial`` scenario; the host-side OGB_cl(B=1) footnote-3 check
 stays on the slow oracle path at quick scale only."""
 

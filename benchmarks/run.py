@@ -31,7 +31,6 @@ from . import (
     serving_slo,
     sized_cdn,
     stream_scale,
-    throughput,
 )
 
 SUITES = {
@@ -44,7 +43,6 @@ SUITES = {
     "fig11": fig11_locality.main,
     "complexity": complexity_scaling.main,
     "kernels": kernel_sweeps.main,
-    "throughput": throughput.main,
     "engines": engines_throughput.main,
     "serving": serving_slo.main,
     "sized": sized_cdn.main,

@@ -217,7 +217,7 @@ class _TracedCollector(ast.NodeVisitor):
         self.generic_visit(node)
 
 
-#: builder prefixes: `_make_ogb_step` RETURNS the traced step, it is not
+#: builder prefixes: `make_ogb_chunk` RETURNS the traced step, it is not
 #: itself traced — its params (sample mode strings, sweep counts) are host
 #: config and branching on them is the whole point of a factory
 _FACTORY_PREFIXES = ("make", "_make", "build", "_build", "get_", "_get_")

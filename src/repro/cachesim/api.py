@@ -44,11 +44,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.cachesim import engines as _engines
 from repro.cachesim import tree_engines as _tree_engines
-from repro.cachesim.replay import (
-    _make_ogb_step,
-    opt_hits_by_combo,
-    sampling_keys,
-)
+from repro.cachesim.engines import OGBCarry, OMDCarry, sampling_keys
 from repro.cachesim.results import RunResult, SweepResult
 from repro.core.ogb import theoretical_eta
 from repro.core.omd import theoretical_eta_omd
@@ -442,6 +438,18 @@ def run(
     )
 
 
+def opt_hits_by_combo(
+    trace_prefix: np.ndarray, combos: list[dict[str, float]]
+) -> np.ndarray:
+    """Hindsight static-OPT per combo, computed host-side once per capacity
+    (OPT depends only on the trace histogram and C)."""
+    opt_by_c = {
+        c: float(best_static_hits(trace_prefix, c))
+        for c in set(int(combo["capacity"]) for combo in combos)
+    }
+    return np.asarray([opt_by_c[int(c["capacity"])] for c in combos])
+
+
 def sweep(
     pd: PolicyDef,
     trace: np.ndarray,
@@ -542,18 +550,6 @@ def sweep(
 # ---------------------------------------------------------------------------
 # carries for the fractional policies (state + traced params + sampling rng)
 # ---------------------------------------------------------------------------
-class OGBCarry(NamedTuple):
-    """OGB_cl state with its per-combo parameters as traced leaves."""
-
-    f: jax.Array  # (N,) float32 fractional state
-    tau: jax.Array  # () float32 previous chunk's projection threshold
-    eta: jax.Array  # () float32 learning rate
-    cap: jax.Array  # () float32 capacity
-    p: jax.Array  # (N,) permanent random numbers (poisson) or (0,)
-    u_key: jax.Array  # (2,) uint32 key data for per-chunk Madow offsets
-    t: jax.Array  # () int32 chunk counter
-
-
 class SizedAutomatonCarry(NamedTuple):
     """A discrete automaton carry paired with per-item byte sizes.
 
@@ -596,44 +592,12 @@ class SizedOGBScanCarry(NamedTuple):
     t: jax.Array  # () int32 chunk counter
 
 
-class OMDApiCarry(NamedTuple):
-    """OMD log-weight state with its per-combo parameters as traced leaves."""
-
-    f: jax.Array  # (N,) float32 fractional state
-    w: jax.Array  # (N,) float32 log-weights (renormalized every chunk)
-    lam: jax.Array  # () float32 last KL-projection threshold
-    eta: jax.Array  # () float32
-    cap: jax.Array  # () float32
-    p: jax.Array  # (N,) or (0,)
-    u_key: jax.Array  # (2,) uint32
-    t: jax.Array  # () int32
-
-
 def _sampling_init(seed: int, catalog_size: int, sample: str):
     """(p, u_key): the shared seed derivation
-    (:func:`repro.cachesim.replay.sampling_keys`), with the Madow key as
+    (:func:`repro.cachesim.engines.sampling_keys`), with the Madow key as
     raw key data so it stacks/donates like any other carry leaf."""
     p, k_u = sampling_keys(seed, catalog_size, sample)
     return p, jax.random.key_data(k_u)
-
-
-def _chunk_u(sample: str, u_key: jax.Array, t: jax.Array) -> jax.Array:
-    """Per-chunk Madow offset, derived from the carried key + chunk counter
-    (counter-mode so streamed/resumed runs draw the same sequence)."""
-    if sample not in ("madow", "madow_tree"):
-        return jnp.zeros((), jnp.float32)
-    k = jax.random.fold_in(jax.random.wrap_key_data(u_key), t)
-    return jax.random.uniform(k, (), jnp.float32)
-
-
-_EMPTY_COUNTS = None  # lazily-created (0,) placeholder for untracked OPT
-
-
-def _empty_counts():
-    global _EMPTY_COUNTS
-    if _EMPTY_COUNTS is None:
-        _EMPTY_COUNTS = jnp.zeros((0,), jnp.float32)
-    return _EMPTY_COUNTS
 
 
 # ---------------------------------------------------------------------------
@@ -646,9 +610,8 @@ def _ogb_def(
     iters: int = DEFAULT_BISECT_ITERS,
     madow_capacity: Optional[int] = None,
 ) -> PolicyDef:
-    raw = _make_ogb_step(
-        sample, projection, sweeps, iters, track_opt=False,
-        madow_capacity=madow_capacity,
+    chunk = _engines.make_ogb_chunk(
+        sample, projection, sweeps, iters, madow_capacity
     )
 
     def init(catalog_size, capacity, *, seed=0, eta=None, horizon=None,
@@ -660,7 +623,7 @@ def _ogb_def(
             )
         if eta is None:
             raise ValueError("ogb init needs eta (run() resolves eta=None)")
-        if sample in ("madow", "madow_tree") and int(madow_capacity) != int(
+        if sample in _engines.MADOW_SAMPLES and int(madow_capacity) != int(
             capacity
         ):
             raise ValueError(
@@ -680,13 +643,8 @@ def _ogb_def(
         )
 
     def step(carry, ids):
-        u = _chunk_u(sample, carry.u_key, carry.t)
-        state = (carry.f, carry.tau, _empty_counts())
-        (f, tau, _), (reward, hits, tau_o, occ) = raw(
-            carry.eta, carry.p, carry.cap, state, (ids, u)
-        )
-        carry = carry._replace(f=f, tau=tau, t=carry.t + 1)
-        return carry, StepOut(reward, hits, tau_o, occ)
+        carry, (reward, hits, tau, occ) = chunk(carry, ids)
+        return carry, StepOut(reward, hits, tau, occ)
 
     return PolicyDef(
         kind="ogb",
@@ -694,7 +652,7 @@ def _ogb_def(
         init=init,
         step=step,
         fractional=True,
-        # Theorem 3.1 tuning at B=1, matching the legacy replay default
+        # Theorem 3.1 tuning at B=1
         default_eta=lambda N, C, T, W: theoretical_eta(C, N, T, 1),
     )
 
@@ -704,9 +662,7 @@ def _omd_def(
     sweeps: int = _engines.DEFAULT_OMD_SWEEPS,
     madow_capacity: Optional[int] = None,
 ) -> PolicyDef:
-    raw = _engines._make_omd_step(
-        sample, sweeps, track_opt=False, madow_capacity=madow_capacity
-    )
+    chunk = _engines.make_omd_chunk(sample, sweeps, madow_capacity)
 
     def init(catalog_size, capacity, *, seed=0, eta=None, horizon=None,
              n_slots=None, sizes=None, costs=None):
@@ -717,7 +673,7 @@ def _omd_def(
             )
         if eta is None:
             raise ValueError("omd init needs eta (run() resolves eta=None)")
-        if sample in ("madow", "madow_tree") and int(madow_capacity) != int(
+        if sample in _engines.MADOW_SAMPLES and int(madow_capacity) != int(
             capacity
         ):
             raise ValueError(
@@ -727,7 +683,7 @@ def _omd_def(
             )
         p, u_key = _sampling_init(seed, catalog_size, sample)
         f0 = capacity / catalog_size
-        return OMDApiCarry(
+        return OMDCarry(
             f=jnp.full(catalog_size, f0, jnp.float32),
             w=jnp.full(catalog_size, float(np.log(f0)), jnp.float32),
             lam=jnp.zeros((), jnp.float32),
@@ -739,13 +695,8 @@ def _omd_def(
         )
 
     def step(carry, ids):
-        u = _chunk_u(sample, carry.u_key, carry.t)
-        state = (carry.f, carry.w, carry.lam, _empty_counts())
-        (f, w, lam, _), (reward, hits, lam_o, occ) = raw(
-            carry.eta, carry.p, carry.cap, state, (ids, u)
-        )
-        carry = carry._replace(f=f, w=w, lam=lam, t=carry.t + 1)
-        return carry, StepOut(reward, hits, lam_o, occ)
+        carry, (reward, hits, lam, occ) = chunk(carry, ids)
+        return carry, StepOut(reward, hits, lam, occ)
 
     return PolicyDef(
         kind="omd",
@@ -753,7 +704,7 @@ def _omd_def(
         init=init,
         step=step,
         fractional=True,
-        # Si Salem et al. tuning at the replay batch size (legacy default)
+        # Si Salem et al. tuning at the replay batch size
         default_eta=lambda N, C, T, W: theoretical_eta_omd(C, N, T, W),
     )
 

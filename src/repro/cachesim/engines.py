@@ -1,13 +1,22 @@
-"""Device-resident baseline policy steps — classic policies as scan automata.
+"""Device-resident dense policy engines: the fractional steps and the
+classic policies as scan automata.
 
-The paper's comparison baselines (LRU / FIFO / LFU, the no-regret FTPL of
-Bhattacharjee et al. and OMD of Si Salem et al.) were host-side per-request
-Python loops (:mod:`repro.core.policies` driven by
-:func:`repro.cachesim.simulator.simulate`), which caps every comparison figure
-at toy scale while OGB alone rides the ``lax.scan`` replay engine
-(:mod:`repro.cachesim.replay`).  This module gives each baseline the same
-device-resident treatment:
+Each engine here owns its carry and its per-chunk step; the execution layer
+lives in :mod:`repro.cachesim.api`, where every kind is registered as a
+:class:`~repro.cachesim.api.PolicyDef` and replayed/swept by the one
+generic engine (the tree engines of :mod:`repro.cachesim.tree_engines`
+follow the same seam).
 
+* **OGB** — the paper's gradient step (a B-element scatter-add) and the
+  capped-simplex projection, warm-started: with a feasible pre-step state
+  the per-chunk threshold provably lies in ``[0, eta * B]``, and the
+  previous chunk's tau seeds a bracketed-Newton root-find
+  (:func:`repro.jaxcache.fractional.capped_simplex_project_warm`) that
+  needs single-digit catalog sweeps instead of ~50 cold bisection sweeps.
+* **OMD** — negative-entropy mirror descent (multiplicative weights with a
+  KL projection onto the capped simplex), sharing the warm-bracket idea:
+  after the log-weight step the threshold provably lies in ``[0, eta * B]``,
+  and a few safeguarded Newton sweeps replace a cold bisection.
 * **LRU / FIFO** — fixed-size slot arrays ``(slots, stamps)``: membership is a
   C-wide compare, the victim is ``argmin(stamps)`` (last-use time for LRU,
   insertion time for FIFO).  Bit-exact vs the OrderedDict policies: the hit
@@ -21,34 +30,30 @@ device-resident treatment:
   maintained by single-swap eviction.  The noise is the *same float32 grid*
   the host policy uses (:func:`repro.core.ftpl.ftpl_noise`), and scores are
   float32 IEEE adds on both sides, so agreement is bit-exact, not approximate.
-* **OMD** — negative-entropy mirror descent (multiplicative weights with a
-  KL projection onto the capped simplex), sharing the warm-bracket idea of
-  :func:`repro.jaxcache.fractional.capped_simplex_project_warm`: after the
-  log-weight step the threshold provably lies in ``[0, eta * B]``, and a few
-  safeguarded Newton sweeps replace a cold bisection.
 
-This module owns the raw per-request/per-chunk step functions and carries;
-the execution layer lives in :mod:`repro.cachesim.api`, where every kind is
-registered as a :class:`~repro.cachesim.api.PolicyDef` and replayed/swept by
-the one generic engine.  The legacy entry points here (``run_engine`` /
-``run_omd`` / ``sweep_engine``) are deprecated thin wrappers over that API.
+The fractional engines share one sampling convention
+(:func:`sampling_keys`, :func:`sample_chunk_metrics`): the seed derivation
+of the Poisson permanent random numbers and the Madow key, and the
+per-chunk reward / hits / occupancy accounting at the pre-update state.
 """
 
 from __future__ import annotations
 
-import functools
-import warnings
-from typing import NamedTuple, Optional, Sequence
+from typing import NamedTuple, Optional
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-from repro.cachesim.replay import sample_chunk_metrics
-from repro.cachesim.results import RunResult, SweepResult
 from repro.core.ftpl import ftpl_initial_top_c, ftpl_noise, theoretical_zeta
-from repro.jaxcache.fractional import warm_bracket_hi
+from repro.jaxcache.fractional import (
+    capped_simplex_project,
+    capped_simplex_project_warm,
+    madow_sample_jax,
+    permanent_random_numbers,
+    warm_bracket_hi,
+)
 
 _I32_MAX = np.int32(np.iinfo(np.int32).max)
 _I32_MIN = np.int32(np.iinfo(np.int32).min)
@@ -57,14 +62,8 @@ _I32_MIN = np.int32(np.iinfo(np.int32).min)
 ENGINE_KINDS = ("lru", "fifo", "lfu", "ftpl")
 DEFAULT_OMD_SWEEPS = 10
 
-#: legacy names — the five result dataclasses are unified in
-#: :mod:`repro.cachesim.results`
-EngineResult = RunResult
-EngineSweepResult = SweepResult
-
-
 # ---------------------------------------------------------------------------
-# carries — ReplayCarry-style NamedTuples of fixed-shape device arrays
+# carries — NamedTuples of fixed-shape device arrays
 # ---------------------------------------------------------------------------
 class SlotCarry(NamedTuple):
     """LRU / FIFO state: C slots with an eviction timestamp each.
@@ -91,13 +90,32 @@ class FTPLCarry(NamedTuple):
     noise: jax.Array  # (N,) float32 one-shot perturbation (constant)
 
 
-class OMDCarry(NamedTuple):
-    """Normalized log-weight state: f = min(1, exp(w)) is always feasible."""
+class OGBCarry(NamedTuple):
+    """OGB_cl state with its per-combo parameters as traced leaves."""
 
-    f: jax.Array  # (N,) float32 fractional cache state
-    w: jax.Array  # (N,) float32 log-weights, renormalized every chunk
-    lam: jax.Array  # () float32 last chunk's KL-projection threshold
-    counts: jax.Array  # (N,) float32 whole-trace histogram (hindsight OPT)
+    f: jax.Array  # (N,) float32 fractional state
+    tau: jax.Array  # () float32 previous chunk's projection threshold
+    eta: jax.Array  # () float32 learning rate
+    cap: jax.Array  # () float32 capacity
+    p: jax.Array  # (N,) permanent random numbers (poisson) or (0,)
+    u_key: jax.Array  # (2,) uint32 key data for per-chunk Madow offsets
+    t: jax.Array  # () int32 chunk counter
+
+
+class OMDCarry(NamedTuple):
+    """OMD log-weight state with its per-combo parameters as traced leaves.
+
+    The weights are renormalized every chunk, so f = min(1, exp(w)) is
+    always feasible."""
+
+    f: jax.Array  # (N,) float32 fractional state
+    w: jax.Array  # (N,) float32 log-weights (renormalized every chunk)
+    lam: jax.Array  # () float32 last KL-projection threshold
+    eta: jax.Array  # () float32
+    cap: jax.Array  # () float32
+    p: jax.Array  # (N,) or (0,)
+    u_key: jax.Array  # (2,) uint32
+    t: jax.Array  # () int32
 
 
 def _padded(active: np.ndarray, n_slots: int, inactive_val: int) -> jnp.ndarray:
@@ -233,33 +251,131 @@ _STEPS = {
 }
 
 
-def make_engine_run(kind: str):
-    """Unjitted whole-trace automaton: ``run(carry, chunks) -> (carry, ys)``.
-
-    ``chunks`` is (M, W) int32; ``ys`` stacks per-chunk (hits, occupancy).
-    Kept unjitted so sweeps can ``vmap`` it; callers wanting a single replay
-    should use :func:`make_engine_fn`.
-    """
-    step = _STEPS[kind]
-
-    def run(carry, chunks):
-        def outer(c, ids):
-            c, hits = jax.lax.scan(step, c, ids)
-            return c, (jnp.sum(hits.astype(jnp.int32)), _occ_slots(c))
-
-        return jax.lax.scan(outer, carry, chunks)
-
-    return run
+# ---------------------------------------------------------------------------
+# fractional engines: the shared sampling convention
+# ---------------------------------------------------------------------------
+#: sampling modes that draw a per-chunk Madow offset u from the carried key
+MADOW_SAMPLES = ("madow", "madow_tree")
 
 
-@functools.lru_cache(maxsize=None)
-def make_engine_fn(kind: str):
-    """Jitted (donated-carry) form of :func:`make_engine_run`."""
-    return jax.jit(make_engine_run(kind), donate_argnums=(0,))
+def sampling_keys(seed: int, catalog_size: int, sample: str) -> tuple:
+    """Seed-derived ``(p, k_u)``: the permanent random numbers for Poisson
+    sampling (size-0 when unused) and the key that drives Madow offsets.
+    THE one seed derivation — every fractional carry (dense and tree)
+    builds on it, so the Poisson stream cannot desync between engines (the
+    goldens pin it)."""
+    k_p, k_u = jax.random.split(jax.random.key(seed))
+    p = (
+        permanent_random_numbers(k_p, catalog_size)
+        if sample == "poisson"
+        else jnp.zeros((0,), jnp.float32)
+    )
+    return p, k_u
+
+
+def _chunk_u(sample: str, u_key: jax.Array, t: jax.Array) -> jax.Array:
+    """Per-chunk Madow offset, derived from the carried key + chunk counter
+    (counter-mode so streamed/resumed runs draw the same sequence)."""
+    if sample not in MADOW_SAMPLES:
+        return jnp.zeros((), jnp.float32)
+    k = jax.random.fold_in(jax.random.wrap_key_data(u_key), t)
+    return jax.random.uniform(k, (), jnp.float32)
+
+
+def sample_chunk_metrics(sample: str, capacity, f, ids, p, u):
+    """(reward, hits, occupancy) for one request chunk at the pre-update
+    state ``f`` (OCO order).  The one definition of the Poisson / Madow /
+    fractional hit-accounting conventions, shared by the OGB and OMD
+    chunks so they cannot drift.
+
+    ``madow_tree`` is the O(C log N) form of ``madow``: the same systematic
+    sample drawn by prefix-tree descent
+    (:func:`repro.kernels.prefix_tree.madow_sample_tree`) instead of an
+    O(N) cumsum + mask — an equally valid draw from the same marginals, but
+    not the bit-identical sample set (float32 tree sums associate
+    differently), so the committed goldens stay on ``madow``."""
+    fi = f[ids]
+    reward = jnp.sum(fi)
+    if sample == "poisson":
+        # hits only need the requested coordinates: B-sized gathers, not an
+        # N-sized mask; occupancy is the one remaining catalog pass
+        hits = jnp.sum((fi >= p[ids]).astype(jnp.int32))
+        occ = jnp.sum((f >= p).astype(jnp.float32))
+    elif sample == "madow":
+        cached = madow_sample_jax(f, u, capacity)
+        hits = jnp.sum(cached[ids].astype(jnp.int32))
+        occ = jnp.sum(cached.astype(jnp.float32))
+    elif sample == "madow_tree":
+        from repro.kernels.prefix_tree import madow_sample_tree
+
+        sel = madow_sample_tree(f, u, capacity)  # (C,) ascending leaf ids
+        pos = jnp.searchsorted(sel, ids)
+        cached = sel[jnp.minimum(pos, capacity - 1)] == ids
+        hits = jnp.sum(cached.astype(jnp.int32))
+        occ = jnp.float32(capacity)
+    else:
+        hits = jnp.zeros((), jnp.int32)
+        occ = jnp.sum(f)
+    return reward, hits, occ
+
+
+def _check_sample(sample: str, madow_capacity: Optional[int]) -> None:
+    if sample not in ("poisson", "madow", "madow_tree", "none"):
+        raise ValueError(f"unknown sample mode {sample!r}")
+    if sample in MADOW_SAMPLES and madow_capacity is None:
+        raise ValueError("madow sampling needs a static capacity")
 
 
 # ---------------------------------------------------------------------------
-# OMD — mirror-descent fractional step (multiplicative analogue of replay)
+# OGB — gradient step + warm-started capped-simplex projection
+# ---------------------------------------------------------------------------
+def make_ogb_chunk(
+    sample: str,
+    projection: str,
+    sweeps: int,
+    iters: int,
+    madow_capacity: Optional[int] = None,
+):
+    """Per-chunk OGB_cl step ``(carry, ids) -> (carry, (reward, hits, tau,
+    occ))`` over an :class:`OGBCarry`; advances ``carry.t``.
+
+    eta and capacity are traced carry leaves, so one compiled step serves
+    a single replay and a vmapped grid.  The chunk size B is read off
+    ``ids.shape`` (static under scan); ``madow_capacity`` must be the
+    static C under Madow sampling (it needs a static sample count).
+    ``projection="bisect"`` is the cold bisection (the tests' reference).
+    """
+    _check_sample(sample, madow_capacity)
+    if projection not in ("warm", "bisect"):
+        raise ValueError(f"unknown projection mode {projection!r}")
+
+    def chunk(carry: OGBCarry, ids):
+        u = _chunk_u(sample, carry.u_key, carry.t)
+        # named scopes (ogb/<phase>) let a device trace split the step
+        with jax.named_scope("ogb/sample"):
+            reward, hits, occ = sample_chunk_metrics(
+                sample, madow_capacity, carry.f, ids, carry.p, u
+            )
+        # gradient step as a B-element scatter-add (duplicates accumulate);
+        # avoids materializing a dense (N,) counts histogram per chunk
+        with jax.named_scope("ogb/gradient"):
+            y = carry.f.at[ids].add(carry.eta)
+        with jax.named_scope("ogb/project"):
+            if projection == "warm":
+                hi = warm_bracket_hi(carry.eta * jnp.float32(ids.shape[0]))
+                f, tau = capped_simplex_project_warm(
+                    y, carry.cap, jnp.float32(0.0), hi, carry.tau, sweeps
+                )
+            else:
+                f, tau = capped_simplex_project(y, carry.cap, iters)
+        carry = carry._replace(f=f, tau=tau, t=carry.t + 1)
+        return carry, (reward, hits, tau, occ)
+
+    return chunk
+
+
+# ---------------------------------------------------------------------------
+# OMD — mirror-descent fractional step (multiplicative analogue of OGB)
 # ---------------------------------------------------------------------------
 def _omd_project(w, cap, hi, sweeps):
     """Safeguarded-Newton KL threshold: lam with sum min(1, e^(w-lam)) = C.
@@ -294,224 +410,30 @@ def _omd_project(w, cap, hi, sweeps):
     return lam
 
 
-def _make_omd_step(
+def make_omd_chunk(
     sample: str,
-    sweeps: int,
-    track_opt: bool,
+    sweeps: int = DEFAULT_OMD_SWEEPS,
     madow_capacity: Optional[int] = None,
 ):
-    """The per-chunk OMD update, with *traced* eta and capacity — the
-    mirror-descent counterpart of :func:`repro.cachesim.replay._make_ogb_step`
-    (same ``step(eta, p, cap, carry, xs)`` contract)."""
-    if sample not in ("poisson", "madow", "madow_tree", "none"):
-        raise ValueError(f"unknown sample mode {sample!r}")
-    if sample in ("madow", "madow_tree") and madow_capacity is None:
-        raise ValueError("madow sampling needs a static capacity")
+    """Per-chunk OMD step ``(carry, ids) -> (carry, (reward, hits, lam,
+    occ))`` over an :class:`OMDCarry` — the mirror-descent counterpart of
+    :func:`make_ogb_chunk` (same contract); advances ``carry.t``."""
+    _check_sample(sample, madow_capacity)
 
-    def step(eta, p, cap, carry, xs):
-        f, w, _lam, counts_tot = carry
-        ids, u = xs
+    def chunk(carry: OMDCarry, ids):
+        u = _chunk_u(sample, carry.u_key, carry.t)
         reward, hits, occ = sample_chunk_metrics(
-            sample, madow_capacity, f, ids, p, u
+            sample, madow_capacity, carry.f, ids, carry.p, u
         )
-        w = w.at[ids].add(eta)
+        eta = carry.eta
+        w = carry.w.at[ids].add(eta)
         lam = _omd_project(
-            w, cap, warm_bracket_hi(eta * jnp.float32(ids.shape[0])), sweeps
+            w, carry.cap, warm_bracket_hi(eta * jnp.float32(ids.shape[0])),
+            sweeps,
         )
         w = w - lam  # renormalize: f = min(1, e^w) stays threshold-free
-        f_new = jnp.minimum(1.0, jnp.exp(w))
-        if track_opt:
-            counts_tot = counts_tot.at[ids].add(1.0)
-        return OMDCarry(f_new, w, lam, counts_tot), (reward, hits, lam, occ)
+        f = jnp.minimum(1.0, jnp.exp(w))
+        carry = carry._replace(f=f, w=w, lam=lam, t=carry.t + 1)
+        return carry, (reward, hits, lam, occ)
 
-    return step
-
-
-@functools.lru_cache(maxsize=64)
-def make_omd_fn(
-    catalog_size: int,
-    capacity: int,
-    batch: int,
-    sample: str = "poisson",
-    sweeps: int = DEFAULT_OMD_SWEEPS,
-    track_opt: bool = True,
-):
-    """Jitted whole-trace OMD replay, interface-compatible with
-    :func:`repro.cachesim.replay.make_replay_fn`:
-    ``replay(carry, chunks, eta, p, us) -> (carry', opt_hits, ys)``.
-    """
-    step = _make_omd_step(sample, sweeps, track_opt, madow_capacity=capacity)
-    cap_f = float(capacity)
-
-    def replay(carry, chunks, eta, p, us):
-        m = chunks.shape[0]
-        if us.shape[0] != m:
-            us = jnp.zeros((m,), jnp.float32)
-        carry, ys = jax.lax.scan(
-            lambda c, x: step(eta, p, jnp.float32(cap_f), c, x),
-            carry,
-            (chunks, us),
-        )
-        if track_opt:
-            opt = jnp.sum(jax.lax.top_k(carry.counts, capacity)[0])
-        else:
-            opt = jnp.zeros((), jnp.float32)
-        return carry, opt, ys
-
-    return jax.jit(replay, donate_argnums=(0,))
-
-
-def init_omd_carry(catalog_size: int, capacity: int) -> OMDCarry:
-    f0 = capacity / catalog_size
-    return OMDCarry(
-        f=jnp.full(catalog_size, f0, jnp.float32),
-        w=jnp.full(catalog_size, float(np.log(f0)), jnp.float32),
-        lam=jnp.zeros((), jnp.float32),
-        counts=jnp.zeros(catalog_size, jnp.float32),
-    )
-
-
-# ---------------------------------------------------------------------------
-# deprecated entry points — thin wrappers over the unified policy engine
-# ---------------------------------------------------------------------------
-def run_engine(
-    kind: str,
-    trace: np.ndarray,
-    catalog_size: int,
-    capacity: int,
-    *,
-    window: int = 10_000,
-    seed: int = 0,
-    zeta: Optional[float] = None,
-    horizon: Optional[int] = None,
-    name: Optional[str] = None,
-) -> RunResult:
-    """Replay a whole trace through one scan automaton.
-
-    .. deprecated::
-        Use ``api.run(api.policy_def(kind), trace, N, C, window=...)``
-        (:mod:`repro.cachesim.api`).
-    """
-    warnings.warn(
-        "run_engine is deprecated; use repro.cachesim.api.run("
-        f"policy_def({kind!r}), ...) instead",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    from repro.cachesim import api
-
-    return api.run(
-        api.policy_def(kind),
-        trace,
-        catalog_size,
-        capacity,
-        window=window,
-        seed=seed,
-        horizon=horizon,
-        track_opt=False,
-        keep_carry=False,  # legacy EngineResult carried no final state
-        name=name,
-        zeta=zeta,
-    )
-
-
-def engine_hit_sequence(
-    kind: str,
-    trace: np.ndarray,
-    catalog_size: int,
-    capacity: int,
-    **kw,
-) -> np.ndarray:
-    """Per-request hit flags (window=1) — the differential-testing probe."""
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        res = run_engine(kind, trace, catalog_size, capacity, window=1, **kw)
-    return res.hits.astype(bool)
-
-
-def run_omd(
-    trace: np.ndarray,
-    catalog_size: int,
-    capacity: int,
-    batch: int,
-    *,
-    eta: Optional[float] = None,
-    sample: str = "poisson",
-    sweeps: int = DEFAULT_OMD_SWEEPS,
-    seed: int = 0,
-    track_opt: bool = True,
-    keep_final_f: bool = False,
-    name: str = "OMD",
-) -> RunResult:
-    """Replay a whole trace through the scan-compiled OMD engine.
-
-    .. deprecated::
-        Use ``api.run(api.policy_def("omd", ...), trace, N, C,
-        window=batch)`` (:mod:`repro.cachesim.api`).  Under
-        ``sample="madow"`` the per-chunk offsets are counter-derived from
-        the carried key (see :func:`repro.cachesim.replay.replay_trace`).
-    """
-    warnings.warn(
-        "run_omd is deprecated; use repro.cachesim.api.run("
-        "policy_def('omd'), ...) instead",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    from repro.cachesim import api
-
-    opts = dict(sample=sample, sweeps=sweeps)
-    if sample == "madow":
-        opts["madow_capacity"] = int(capacity)
-    res = api.run(
-        api.policy_def("omd", **opts),
-        trace,
-        catalog_size,
-        capacity,
-        window=batch,
-        eta=eta,
-        seed=seed,
-        track_opt=track_opt,
-        keep_carry=keep_final_f,  # legacy footprint: final state is opt-in
-        name=name,
-    )
-    res.extras["sweeps"] = float(sweeps)
-    return res
-
-
-def sweep_engine(
-    kind: str,
-    trace: np.ndarray,
-    catalog_size: int,
-    capacities: Sequence[int],
-    *,
-    seeds: Sequence[int] = (0,),
-    window: int = 10_000,
-    zeta: Optional[float] = None,
-    horizon: Optional[int] = None,
-    track_opt: bool = True,
-) -> SweepResult:
-    """Run one automaton over a (capacity x seed) grid in a single dispatch.
-
-    .. deprecated::
-        Use ``api.sweep(api.policy_def(kind), trace, N, capacities, ...)``
-        (:mod:`repro.cachesim.api`).
-    """
-    warnings.warn(
-        "sweep_engine is deprecated; use repro.cachesim.api.sweep("
-        f"policy_def({kind!r}), ...) instead",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    from repro.cachesim import api
-
-    return api.sweep(
-        api.policy_def(kind),
-        trace,
-        catalog_size,
-        capacities,
-        seeds=seeds,
-        window=window,
-        horizon=horizon,
-        track_opt=track_opt,
-        zeta=zeta,
-    )
+    return chunk

@@ -3,11 +3,9 @@
 One home for the host-side views every execution path returns:
 
 * :class:`RunResult` — one policy replayed over one trace (any kind: the
-  fractional gradient policies and the discrete automata share it).  The
-  legacy names (``ReplayMetrics``, ``EngineResult``) are aliases.
+  fractional gradient policies and the discrete automata share it).
 * :class:`SweepResult` — a stacked (capacities x seeds x etas) grid run in
-  one vmapped dispatch.  Legacy ``ReplaySweepResult`` / ``EngineSweepResult``
-  are aliases.
+  one vmapped dispatch.
 * :class:`StreamResult` — a :class:`RunResult` accumulated out-of-core by
   :func:`repro.cachesim.tracelab.stream.run_stream`, extended with the
   windowed time-varying-OPT ("dynamic regret") accounting.
@@ -86,24 +84,6 @@ class RunResult(HitStatsMixin):
     extras: Dict[str, float] = field(default_factory=dict)
     byte_hits: Optional[np.ndarray] = None  # (M,) per-chunk byte hits (sized)
     bytes_total: float = 0.0  # total bytes requested (sized runs, else 0)
-
-    # legacy spellings (ReplayMetrics / EngineResult)
-    @property
-    def batch(self) -> int:
-        return self.window
-
-    @property
-    def frac_reward(self) -> np.ndarray:
-        return self.reward
-
-    @property
-    def taus(self) -> np.ndarray:
-        return self.aux
-
-    @property
-    def final_f(self) -> Optional[np.ndarray]:
-        f = getattr(self.carry, "f", None)
-        return None if f is None else np.asarray(f)
 
     @property
     def frac_hit_ratio(self) -> float:
@@ -230,23 +210,11 @@ class SweepResult:
     bytes_total: float = 0.0  # total bytes requested (sized runs, else 0)
 
     @property
-    def batch(self) -> int:
-        return self.window
-
-    @property
     def byte_hit_ratios(self) -> np.ndarray:
         """Per-combo byte hit ratio (falls back to object ratio unsized)."""
         if self.byte_hits is None or self.bytes_total <= 0.0:
             return self.hit_ratios
         return self.byte_hits.sum(axis=1) / self.bytes_total
-
-    @property
-    def frac_reward(self) -> np.ndarray:
-        return self.reward
-
-    @property
-    def taus(self) -> np.ndarray:
-        return self.aux
 
     @property
     def hit_ratios(self) -> np.ndarray:

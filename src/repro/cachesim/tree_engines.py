@@ -698,7 +698,7 @@ def init_ogb_tree_carry(
     passes.  A larger actual window than the hint is still correct — the
     re-anchor trigger watches the real chunk size — it just re-anchors
     more often."""
-    from repro.cachesim.replay import sampling_keys
+    from repro.cachesim.engines import sampling_keys
 
     n, v = int(catalog_size), int(buckets)
     span = 1.0 + 2.0 * OGB_TREE_GAIN * max(1.0, float(eta) * batch_hint)
@@ -1031,7 +1031,7 @@ def init_sized_ogb_tree_carry(
     (exact when there are that few distinct sizes — see
     :func:`repro.core.ogb_sized.size_classes`); ``costs`` default to the
     (quantized) sizes, i.e. byte-weighted rewards w_{t,i} = s_i."""
-    from repro.cachesim.replay import sampling_keys
+    from repro.cachesim.engines import sampling_keys
     from repro.core.ogb_sized import size_classes
 
     n, v = int(catalog_size), int(buckets)

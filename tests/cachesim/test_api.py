@@ -1,6 +1,6 @@
-"""The unified policy protocol: parity, streaming, deprecation.
+"""The unified policy protocol: parity and streaming.
 
-Three contracts lock the api redesign down:
+Two contracts lock the api redesign down:
 
 * **Golden parity** — ``api.run`` must reproduce the committed per-scenario
   fixtures for every registered kind, using only the public protocol (no
@@ -8,13 +8,10 @@ Three contracts lock the api redesign down:
   float32 allowance for the fractional engines.
 * **Streaming** — two chunked ``run`` calls with a handed-off carry replay
   the same dynamics as one full run, bit for bit, for every kind.
-* **Deprecation** — each legacy entry point still works but warns, and
-  returns the same numbers as the api path it forwards to.
 """
 
 import json
 import os
-import warnings
 
 import numpy as np
 import pytest
@@ -145,54 +142,6 @@ def test_resume_rejects_init_params():
 def test_unknown_kind_lists_registry():
     with pytest.raises(KeyError, match="registered"):
         api.policy_def("nope")
-
-
-# ---------------------------------------------------------------------------
-# legacy wrappers: still correct, but warn
-# ---------------------------------------------------------------------------
-def _legacy_calls():
-    from repro.cachesim.engines import run_engine, run_omd, sweep_engine
-    from repro.cachesim.replay import replay_trace, sweep_replay
-
-    trace = zipf(N, 640, alpha=0.9, seed=7)
-    return [
-        ("replay_trace", lambda: replay_trace(trace, N, C, batch=16)),
-        ("run_omd", lambda: run_omd(trace, N, C, 16)),
-        ("run_engine", lambda: run_engine("lru", trace, N, C, window=16)),
-        (
-            "sweep_replay",
-            lambda: sweep_replay(trace, N, capacities=[C], batch=16),
-        ),
-        (
-            "sweep_engine",
-            lambda: sweep_engine("lru", trace, N, capacities=[C], window=16),
-        ),
-    ]
-
-
-@pytest.mark.parametrize(
-    "name,call", _legacy_calls(), ids=[n for n, _ in _legacy_calls()]
-)
-def test_legacy_wrappers_deprecated(name, call):
-    with pytest.warns(DeprecationWarning, match=name):
-        res = call()
-    assert res.T == 640
-
-
-def test_legacy_wrapper_matches_api():
-    """The wrapper and the api path are the same computation."""
-    trace = zipf(N, 640, alpha=0.9, seed=9)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from repro.cachesim.replay import replay_trace
-
-        legacy = replay_trace(trace, N, C, batch=16, eta=0.03, seed=0)
-    direct = api.run(
-        api.policy_def("ogb"), trace, N, C, window=16, eta=0.03, seed=0
-    )
-    np.testing.assert_array_equal(legacy.hits, direct.hits)
-    np.testing.assert_array_equal(legacy.reward, direct.reward)
-    assert legacy.opt_hits == direct.opt_hits
 
 
 def test_public_surface():

@@ -4,27 +4,30 @@ The engines' whole value is that compiling LRU/FIFO/LFU/FTPL into
 ``lax.scan`` slot automata changes *nothing* about the replayed dynamics —
 the per-request hit sequence must equal the host policy's exactly (not in
 distribution, not within tolerance) on every trace family, and OMD must
-match a float64 numpy oracle within float32 headroom.
+match a float64 numpy oracle within float32 headroom.  The dense
+fractional chunks (``make_ogb_chunk`` / ``make_omd_chunk``) are pinned
+against ``api.run`` bit for bit.
 """
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
+from repro.cachesim import api
 from repro.cachesim.engines import (
+    DEFAULT_OMD_SWEEPS,
     ENGINE_KINDS,
-    engine_hit_sequence,
+    MADOW_SAMPLES,
     init_engine_carry,
-    make_engine_fn,
-    run_engine,
-    run_omd,
-    sweep_engine,
+    make_ogb_chunk,
+    make_omd_chunk,
 )
-from repro.cachesim.replay import replay_trace, sweep_replay
 from repro.cachesim.traces import adversarial, bursty, zipf
 from repro.core.omd import OMDClassic, project_capped_simplex_kl
 from repro.core.policies import make_policy
+from repro.jaxcache.fractional import DEFAULT_BISECT_ITERS, DEFAULT_WARM_SWEEPS
 
 N, C, T = 311, 23, 6000
 
@@ -33,6 +36,15 @@ TRACES = {
     "adversarial": lambda: adversarial(N, T, seed=4),
     "bursty": lambda: bursty(N, T, seed=5),
 }
+
+
+def engine_hit_sequence(kind, trace, n, c, **kw):
+    """Per-request hit flags of the device engine (window=1)."""
+    res = api.run(
+        api.policy_def(kind), trace, n, c, window=1, track_opt=False,
+        keep_carry=False, **kw,
+    )
+    return res.hits.astype(bool)
 
 
 def _host_hits(kind, trace, n, c, **kw):
@@ -68,7 +80,8 @@ def test_windowed_hits_partition_sequence():
     """Chunked replay (window > 1) sums the same per-request bits."""
     trace = TRACES["zipf"]()
     seq = engine_hit_sequence("lru", trace, N, C)
-    res = run_engine("lru", trace, N, C, window=500)
+    res = api.run(api.policy_def("lru"), trace, N, C, window=500,
+                  track_opt=False)
     np.testing.assert_array_equal(
         res.hits, seq[: res.T].reshape(-1, 500).sum(axis=1)
     )
@@ -107,15 +120,14 @@ def test_omd_engine_matches_float64_oracle_pointwise():
     tracks the exact float64 oracle coordinate by coordinate."""
     B, eta = 16, 0.05
     trace = TRACES["zipf"]()[: 100 * B]
-    m = run_omd(
-        trace, N, C, B, eta=eta, sample="none", keep_final_f=True,
-        track_opt=True,
+    m = api.run(
+        api.policy_def("omd", sample="none"), trace, N, C, window=B, eta=eta
     )
     pol = OMDClassic(N, C, eta=eta, batch_size=B, integral=False)
     for j in trace[: m.T]:
         pol.request(int(j))
-    np.testing.assert_allclose(m.final_f, pol.f, atol=5e-5)
-    rewards = np.asarray(m.frac_reward)
+    np.testing.assert_allclose(np.asarray(m.carry.f), pol.f, atol=5e-5)
+    rewards = np.asarray(m.reward)
     assert abs(rewards.sum() - pol.fractional_reward) < 1e-4 * max(
         pol.fractional_reward, 1.0
     )
@@ -155,22 +167,22 @@ def test_omd_long_horizon_aggregates_and_feasibility():
     feasibility and threshold bracket must all hold."""
     trace = TRACES["zipf"]()
     B, eta = 16, 0.05
-    m = run_omd(
-        trace, N, C, B, eta=eta, sample="none", keep_final_f=True,
-        track_opt=True,
+    m = api.run(
+        api.policy_def("omd", sample="none"), trace, N, C, window=B, eta=eta
     )
     pol = OMDClassic(N, C, eta=eta, batch_size=B, integral=False)
     for j in trace[: m.T]:
         pol.request(int(j))
-    rewards = np.asarray(m.frac_reward)
+    rewards = np.asarray(m.reward)
     assert abs(rewards.sum() - pol.fractional_reward) < 2e-3 * max(
         pol.fractional_reward, 1.0
     )
     # feasibility: the device state stays on the capped simplex
-    assert abs(float(np.sum(m.final_f)) - C) < 1e-3
-    assert np.all(m.final_f >= 0) and np.all(m.final_f <= 1 + 1e-6)
+    final_f = np.asarray(m.carry.f)
+    assert abs(float(np.sum(final_f)) - C) < 1e-3
+    assert np.all(final_f >= 0) and np.all(final_f <= 1 + 1e-6)
     # the KL thresholds stay in the provable [0, eta*B] bracket
-    assert np.all(m.taus >= 0) and np.all(m.taus <= eta * B * (1 + 1e-4) + 1e-6)
+    assert np.all(m.aux >= 0) and np.all(m.aux <= eta * B * (1 + 1e-4) + 1e-6)
 
 
 def test_kl_projection_oracle_properties():
@@ -190,9 +202,9 @@ def test_kl_projection_oracle_properties():
 def test_omd_learns_on_skewed_traffic():
     """Sanity: mirror descent concentrates mass on the hot set."""
     trace = zipf(N, 20_000, alpha=1.2, seed=9)
-    m = run_omd(trace, N, C, 100, sample="none", keep_final_f=True)
+    m = api.run(api.policy_def("omd", sample="none"), trace, N, C, window=100)
     hot = np.argsort(np.bincount(trace, minlength=N))[-C // 2 :]
-    assert m.final_f[hot].mean() > 3.0 * (C / N)
+    assert np.asarray(m.carry.f)[hot].mean() > 3.0 * (C / N)
     w = m.windowed_frac_ratio(m.T // 4)
     assert w[-1] > w[0]  # the transient moves the right way
 
@@ -203,11 +215,10 @@ def test_omd_learns_on_skewed_traffic():
 def test_sweep_engine_rows_match_single_runs():
     trace = TRACES["zipf"]()
     caps = [7, 23]
-    sw = sweep_engine(
-        "lru", trace, N, caps, seeds=(0,), window=500, track_opt=True
-    )
+    lru = api.policy_def("lru")
+    sw = api.sweep(lru, trace, N, caps, seeds=(0,), window=500, track_opt=True)
     for cap in caps:
-        single = run_engine("lru", trace, N, cap, window=500)
+        single = api.run(lru, trace, N, cap, window=500, track_opt=False)
         r = sw.row(capacity=cap)
         np.testing.assert_array_equal(sw.hits[r], single.hits)
         np.testing.assert_array_equal(sw.occupancy[r], single.occupancy)
@@ -216,47 +227,83 @@ def test_sweep_engine_rows_match_single_runs():
 
 def test_sweep_engine_ftpl_seeds_differ():
     trace = TRACES["zipf"]()
-    sw = sweep_engine(
-        "ftpl", trace, N, [C], seeds=(0, 1), window=500, horizon=T
-    )
+    ftpl = api.policy_def("ftpl")
+    sw = api.sweep(ftpl, trace, N, [C], seeds=(0, 1), window=500, horizon=T)
     assert not np.array_equal(
         sw.hits[sw.row(seed=0)], sw.hits[sw.row(seed=1)]
     )
     # and each seed row matches its single replay exactly
-    single = run_engine("ftpl", trace, N, C, window=500, seed=1, horizon=T)
+    single = api.run(
+        ftpl, trace, N, C, window=500, seed=1, horizon=T, track_opt=False
+    )
     np.testing.assert_array_equal(sw.hits[sw.row(seed=1)], single.hits)
 
 
 def test_sweep_replay_grid_matches_single():
     trace = TRACES["zipf"]()
-    sw = sweep_replay(
-        trace, N, capacities=[11, 23], etas=[0.03, None], seeds=(0,), batch=16
+    ogb = api.policy_def("ogb")
+    sw = api.sweep(
+        ogb, trace, N, [11, 23], etas=[0.03, None], seeds=(0,), window=16
     )
     assert len(sw.combos) == 4
-    single = replay_trace(trace, N, 23, batch=16, eta=0.03, seed=0)
+    single = api.run(ogb, trace, N, 23, window=16, eta=0.03, seed=0)
     r = sw.row(capacity=23, eta=0.03)
-    np.testing.assert_allclose(sw.frac_reward[r], single.frac_reward, atol=1e-3)
+    np.testing.assert_allclose(sw.reward[r], single.reward, atol=1e-3)
     np.testing.assert_array_equal(sw.hits[r], single.hits)
     assert sw.opt_hits[r] == single.opt_hits
     assert sw.regrets[r] == pytest.approx(single.regret, abs=1e-2)
-    # eta=None rows must resolve to replay_trace's default tuning, so a
+    # eta=None rows must resolve to run's default tuning, so a
     # default-tuned sweep reproduces default-tuned single replays exactly
-    default = replay_trace(trace, N, 11, batch=16, seed=0)
+    default = api.run(ogb, trace, N, 11, window=16, seed=0)
     r_def = sw.row(capacity=11, eta=default.extras["eta"])
     np.testing.assert_array_equal(sw.hits[r_def], default.hits)
-    np.testing.assert_allclose(
-        sw.frac_reward[r_def], default.frac_reward, atol=1e-3
-    )
+    np.testing.assert_allclose(sw.reward[r_def], default.reward, atol=1e-3)
 
 
 def test_engine_carry_capacity_padding_inert():
     """Padded (inactive) slots never cache anything: a padded sweep row
     equals the unpadded replay."""
-    trace = TRACES["bursty"]()
-    padded = init_engine_carry("lru", N, 7, n_slots=23)
-    fn = make_engine_fn("lru")
-    chunks = jnp.asarray(trace[:5000].reshape(-1, 100), jnp.int32)
-    _carry, (hits_pad, occ_pad) = fn(padded, chunks)
-    res = run_engine("lru", trace[:5000], N, 7, window=100)
-    np.testing.assert_array_equal(np.asarray(hits_pad), res.hits)
-    assert int(np.max(np.asarray(occ_pad))) <= 7
+    trace = TRACES["bursty"]()[:5000]
+    padded = api.run(
+        api.policy_def("lru", impl="dense"), trace, N, 7, window=100,
+        n_slots=23, track_opt=False,
+    )
+    res = api.run(api.policy_def("lru"), trace, N, 7, window=100,
+                  track_opt=False)
+    np.testing.assert_array_equal(padded.hits, res.hits)
+    assert int(np.max(padded.occupancy)) <= 7
+
+
+# ---------------------------------------------------------------------------
+# the dense fractional chunks == api.run, bit for bit
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("sample", ["poisson", "madow", "madow_tree", "none"])
+@pytest.mark.parametrize("kind", ["ogb", "omd"])
+def test_dense_chunk_matches_api_run(kind, sample):
+    """Scanning the engine's chunk from the PolicyDef's init carry gives
+    api.run's per-window observables exactly: the chunk is the whole step."""
+    trace = TRACES["zipf"]()[:640]
+    B, eta, seed = 16, 0.05, 3
+    madow_capacity = C if sample in MADOW_SAMPLES else None
+    opts = {"sample": sample}
+    if madow_capacity is not None:
+        opts["madow_capacity"] = madow_capacity
+    pd = api.policy_def(kind, **opts)
+    if kind == "ogb":
+        chunk = make_ogb_chunk(sample, "warm", DEFAULT_WARM_SWEEPS,
+                               DEFAULT_BISECT_ITERS, madow_capacity)
+    else:
+        chunk = make_omd_chunk(sample, DEFAULT_OMD_SWEEPS, madow_capacity)
+    carry = pd.init(N, C, seed=seed, eta=eta, horizon=len(trace))
+    chunks = jnp.asarray(trace.reshape(-1, B), jnp.int32)
+    carry, (reward, hits, aux, occ) = jax.jit(
+        lambda c, x: jax.lax.scan(chunk, c, x)
+    )(carry, chunks)
+    res = api.run(pd, trace, N, C, window=B, eta=eta, seed=seed,
+                  track_opt=False)
+    np.testing.assert_array_equal(np.asarray(reward, np.float64), res.reward)
+    np.testing.assert_array_equal(np.asarray(hits, np.int64), res.hits)
+    np.testing.assert_array_equal(np.asarray(aux, np.float64), res.aux)
+    np.testing.assert_array_equal(np.asarray(occ, np.float64), res.occupancy)
+    assert int(carry.t) == len(chunks)
+    np.testing.assert_array_equal(np.asarray(carry.f), np.asarray(res.carry.f))

@@ -1,8 +1,8 @@
-"""Scan-compiled replay == per-batch reference == float64 eager oracle.
+"""Scan-compiled OGB replay == per-batch reference == float64 eager oracle.
 
-The replay engine's whole point is that compiling the trace into one
-``lax.scan`` with a warm-started projection changes *nothing* about the
-replayed dynamics — every metric must match the per-batch
+Compiling the trace into one ``lax.scan`` (``api.run`` over
+``policy_def("ogb")``) with a warm-started projection must change *nothing*
+about the replayed dynamics — every metric must match the per-batch
 ``ogb_batch_update`` driver and (within float32 tolerance) the exact float64
 numpy oracle, on both random and adversarial traces.
 """
@@ -13,11 +13,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.cachesim.replay import replay_trace
+from repro.cachesim import api
 from repro.cachesim.traces import adversarial, zipf
 from repro.core.projection import capped_simplex_tau, project_capped_simplex
 from repro.core.regret import best_static_hits
 from repro.jaxcache.fractional import (
+    DEFAULT_WARM_SWEEPS,
     FractionalState,
     capped_simplex_project,
     capped_simplex_project_warm,
@@ -67,32 +68,34 @@ def _oracle_reference(trace, n, c, b, eta):
 def test_scan_equals_per_batch_and_oracle(make_trace):
     trace = make_trace()
     eta = 0.03
-    m = replay_trace(trace, N, C, batch=B, eta=eta, seed=0, keep_final_f=True)
+    m = api.run(api.policy_def("ogb"), trace, N, C, window=B, eta=eta, seed=0)
+    final_f = np.asarray(m.carry.f)
 
     ref_rewards, ref_hits, ref_f = _per_batch_reference(trace, N, C, B, eta)
-    np.testing.assert_allclose(m.frac_reward, ref_rewards, atol=1e-3)
+    np.testing.assert_allclose(m.reward, ref_rewards, atol=1e-3)
     np.testing.assert_array_equal(m.hits, ref_hits)
-    np.testing.assert_allclose(m.final_f, ref_f, atol=5e-6)
+    np.testing.assert_allclose(final_f, ref_f, atol=5e-6)
 
     orc_rewards, orc_f = _oracle_reference(trace, N, C, B, eta)
-    np.testing.assert_allclose(m.frac_reward, orc_rewards, atol=5e-3)
-    np.testing.assert_allclose(m.final_f, orc_f, atol=5e-5)
+    np.testing.assert_allclose(m.reward, orc_rewards, atol=5e-3)
+    np.testing.assert_allclose(final_f, orc_f, atol=5e-5)
 
 
 def test_warm_tau_equals_cold_bisection():
     """Single-digit warm sweeps must match 50-sweep cold bisection to 1e-6."""
     trace = zipf(N, 800, alpha=0.8, seed=7)
     eta = 0.05
-    m_warm = replay_trace(trace, N, C, batch=B, eta=eta, projection="warm")
-    m_cold = replay_trace(trace, N, C, batch=B, eta=eta, projection="bisect")
-    assert m_warm.extras["sweeps"] <= 10
-    np.testing.assert_allclose(m_warm.taus, m_cold.taus, atol=1e-6)
-    np.testing.assert_allclose(
-        m_warm.frac_reward, m_cold.frac_reward, atol=1e-3
-    )
+    sweeps = DEFAULT_WARM_SWEEPS
+    warm = api.policy_def("ogb", projection="warm", sweeps=sweeps)
+    cold = api.policy_def("ogb", projection="bisect")
+    m_warm = api.run(warm, trace, N, C, window=B, eta=eta)
+    m_cold = api.run(cold, trace, N, C, window=B, eta=eta)
+    assert sweeps <= 10
+    np.testing.assert_allclose(m_warm.aux, m_cold.aux, atol=1e-6)
+    np.testing.assert_allclose(m_warm.reward, m_cold.reward, atol=1e-3)
     # and both match the exact float64 tau step by step
     f = np.full(N, C / N, dtype=np.float64)
-    for i, tau_w in enumerate(m_warm.taus):
+    for i, tau_w in enumerate(m_warm.aux):
         y = f + eta * np.bincount(
             trace[i * B : (i + 1) * B], minlength=N
         )
@@ -140,17 +143,18 @@ def test_ogb_batch_update_warm_chains():
 
 def test_opt_and_regret_match_host_reference():
     trace = zipf(N, 960, alpha=1.0, seed=9)
-    m = replay_trace(trace, N, C, batch=B, seed=1)
+    m = api.run(api.policy_def("ogb"), trace, N, C, window=B, seed=1)
     assert m.opt_hits == best_static_hits(trace[: m.T], C)
-    assert m.regret == pytest.approx(m.opt_hits - m.frac_reward.sum())
+    assert m.regret == pytest.approx(m.opt_hits - m.reward.sum())
     # no-regret sanity: the fractional reward is within the paper's bound of
     # OPT for this short horizon (loose check, not the theorem constant)
-    assert m.frac_reward.sum() > 0.25 * m.opt_hits
+    assert m.reward.sum() > 0.25 * m.opt_hits
 
 
 def test_madow_sampling_occupancy_exact():
     trace = zipf(N, 480, alpha=0.9, seed=13)
-    m = replay_trace(trace, N, C, batch=B, sample="madow", seed=2)
+    pd = api.policy_def("ogb", sample="madow", madow_capacity=C)
+    m = api.run(pd, trace, N, C, window=B, seed=2)
     # Madow draws exactly C items each chunk (fp cumsum tolerance +-1)
     assert np.all(np.abs(m.occupancy - C) <= 1)
     assert 0.0 <= m.hit_ratio <= 1.0
@@ -158,7 +162,7 @@ def test_madow_sampling_occupancy_exact():
 
 def test_windowed_metrics_partition_totals():
     trace = zipf(N, 640, alpha=0.9, seed=17)
-    m = replay_trace(trace, N, C, batch=B, seed=3)
+    m = api.run(api.policy_def("ogb"), trace, N, C, window=B, seed=3)
     w = m.windowed_hit_ratio(160)
     assert w.shape == (4,)
     np.testing.assert_allclose(
